@@ -10,6 +10,8 @@ import sys
 import time
 import traceback
 
+from repro.utils.compile_cache import enable_compile_cache
+
 SUITES = ("query", "pruning", "ood", "metrics", "construction", "updates",
           "hardware", "params", "stream", "adaptive", "serving",
           "robustness")
@@ -20,6 +22,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma-separated subset of: " + ",".join(SUITES))
     args, _ = ap.parse_known_args()
+    enable_compile_cache()
     chosen = [s for s in args.only.split(",") if s] or list(SUITES)
     print("name,us_per_call,derived")
     t_all = time.perf_counter()

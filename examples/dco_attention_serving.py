@@ -18,9 +18,11 @@ import numpy as np
 from repro.serving.dco_attention import (dco_decode_attention,
                                          exact_decode_attention,
                                          fit_key_rotation)
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     B, S, Hkv, G, hd = 4, 4096, 4, 4, 64
     H = Hkv * G

@@ -14,9 +14,11 @@ sys.path.insert(0, "src")
 from repro.api import open_index
 from repro.vecdata import load_dataset
 from repro.vecdata.synthetic import recall_at_k
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ds = load_dataset("gist", scale=0.2)          # 6k x 960 image embeddings
     print(f"dataset: {ds.name}  N={ds.n}  D={ds.dim}")
     gt, _ = ds.ground_truth(10)

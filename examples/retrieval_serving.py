@@ -24,9 +24,11 @@ from repro.api import SchedulePolicy, open_index
 from repro.launch.mesh import make_host_mesh
 from repro.vecdata import load_dataset
 from repro.vecdata.synthetic import recall_at_k
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ds = load_dataset("sift", scale=0.3)          # 30k x 128
     mesh = make_host_mesh(4, 2)
     sess = open_index(ds.X, index="flat", method="PDScanning+", backend="jax",

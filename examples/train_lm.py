@@ -22,9 +22,11 @@ from repro.configs.base import RunShape
 from repro.models import build_model
 from repro.train.fault import StepMonitor, run_resumable
 from repro.train.train_step import init_state, make_train_step
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=40)
     ap.add_argument("--d-model", type=int, default=256)

@@ -435,6 +435,14 @@ class JaxBackend:
             self._mesh_row_block = _aligned_row_block(
                 per_shard, self.policy.row_block)
 
+    def engine_config(self, k: int):
+        """The resolved ``DcoEngineConfig`` that ``search(Q, k)`` runs
+        (``use_kernel`` decided, PDX groups and mesh row block applied);
+        materializes the device state on first use."""
+        if self._dstate is None:
+            self._materialize()
+        return self._config(k)
+
     def _config(self, k: int, anytime: bool = False, demoted: bool = False):
         from repro.core.jax_engine import DcoEngineConfig
 

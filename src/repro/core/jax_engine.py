@@ -36,6 +36,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+#: Precision of every dot whose result is a lower bound, a completed
+#: distance or tau.  A TPU f32 dot at default precision is one bf16 pass,
+#: which breaks the certificate; HIGHEST keeps f32 accuracy.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class DcoEngineConfig:
@@ -103,12 +108,6 @@ def rule_scalars(extras: dict, d1: int) -> dict:
     return out
 
 
-def rotate_queries(W: jax.Array, Q: jax.Array) -> jax.Array:
-    """Batched online pre-processing: one matmul amortizes the O(D^2) cost
-    the paper identifies as the ultra-high-D bottleneck."""
-    return Q @ W
-
-
 def _estimate(cfg: DcoEngineConfig, partial, D, state, q_extra):
     d1 = cfg.d1
     if cfg.kind in ("lb", "fdscan"):
@@ -141,13 +140,16 @@ def _two_stage_topk_padded(state: dict, q_lead: jax.Array, q_tail: jax.Array,
     def one_chunk(qs):
         ql, qt, qe = qs                                    # (c, d1), (c, Dt)
         # ---- stage 1: one contiguous-stream matmul --------------------
-        partial = (state["lead_sq"][None, :] - 2.0 * ql @ x_lead.T
+        partial = (state["lead_sq"][None, :]
+                   - 2.0 * jnp.matmul(ql, x_lead.T, precision=HIGHEST)
                    + (ql ** 2).sum(1)[:, None])            # (c, n)
         partial = jnp.maximum(partial, 0.0)
         est = _estimate(cfg, partial, D, state, qe)
         if cfg.kind == "fdscan":
-            exact = partial + (state["tail_sq"][None, :] - 2.0 * qt @ x_tail.T
-                               + (qt ** 2).sum(1)[:, None])
+            exact = partial + (
+                state["tail_sq"][None, :]
+                - 2.0 * jnp.matmul(qt, x_tail.T, precision=HIGHEST)
+                + (qt ** 2).sum(1)[:, None])
             dists, ids = jax.lax.top_k(-exact, k)
             return -dists, ids, jnp.full((ql.shape[0],), n, jnp.int32)
         # ---- anchor: certified tau from k exact completions -----------
@@ -242,7 +244,6 @@ def make_distributed_topk(mesh, cfg: DcoEngineConfig, shard_axes=("data", "model
     facade sessions never hit it.  ``None`` preserves the old
     caller-beware behavior."""
     from jax.sharding import PartitionSpec as P
-    import jax.experimental.shard_map as shard_map
 
     if engine not in ("stream", "two_stage"):
         raise ValueError(f"engine must be 'stream' or 'two_stage', got {engine!r}")
@@ -298,9 +299,9 @@ def make_distributed_topk(mesh, cfg: DcoEngineConfig, shard_axes=("data", "model
         return -best, jnp.take_along_axis(ig, pos, axis=1), surv, dmin
 
     spec_x = P(shard_axes)      # rows sharded over the product of axes
-    return shard_map.shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(spec_x, spec_x, spec_x, spec_x, P(), P(), P()),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )

@@ -463,20 +463,26 @@ class DDCopq(DCOMethod):
 
     def train(self, sample_queries: np.ndarray, k: int, schedule=None,
               *, candidates_per_query: int = 2048, fpr: float = 0.002, seed: int = 0):
-        """Calibrate the per-k adist threshold on sampled queries (Alg. 3)."""
+        """Calibrate the per-k adist threshold on sampled queries (Alg. 3).
+
+        ``candidates_per_query >= N`` calibrates against each query's true
+        k-th neighbour over the whole corpus."""
         rng = np.random.default_rng(seed)
         ctx = self.prep_queries(sample_queries)
         N = self.state["N"]
+        codes = self.state["pq"]["codes"]
         ratios = []
         for qi in range(sample_queries.shape[0]):
-            ids = rng.choice(N, size=min(candidates_per_query, N), replace=False)
+            # the whole corpus as a basic slice: a view, not a per-query copy
+            ids = (np.s_[:] if candidates_per_query >= N
+                   else rng.choice(N, size=candidates_per_query, replace=False))
             full = self.exact_sq(ids, ctx, qi)
             tau_sq = np.partition(full, k - 1)[k - 1]
             pos = full <= tau_sq
             if not pos.any():
                 continue
-            adist = T.pq_adist(self.state["pq"], ctx["luts"][qi], self.state["pq"]["codes"][ids])
-            ratios.append(adist[pos] / max(float(tau_sq), 1e-12))
+            adist = T.pq_adist(self.state["pq"], ctx["luts"][qi], codes[ids][pos])
+            ratios.append(adist / max(float(tau_sq), 1e-12))
         allr = np.concatenate(ratios) if ratios else np.array([1.0])
         self.state["models"][k] = float(np.quantile(allr, 1.0 - fpr))
         self.state["trained_k"] = k

@@ -79,7 +79,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.jax_engine import DcoEngineConfig
+from repro.core.jax_engine import HIGHEST, DcoEngineConfig
 
 
 def _round8(v: int) -> int:
@@ -254,10 +254,12 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
     C = min(cfg.block_capacity, B)
     d1, Dt = ql.shape[1], qt.shape[1]
     # Mosaic requires (8, 128)-multiple tiles on real TPUs; interpret mode
-    # (CPU) keeps tight tiles so tests don't pay for lane padding
+    # (CPU) keeps tight tiles so tests don't pay for lane padding.  The
+    # pq_lookup query tile spans the whole (8-padded) chunk: its (N, Q)
+    # output block is then the full lane dim, which Mosaic accepts
     if cfg.use_kernel and _on_tpu():
         kb = dict(block_n=256, block_q=128, block_d=128)
-        kb_pq = dict(block_n=128, block_q=8)
+        kb_pq = dict(block_n=128, block_q=_round8(c))
     else:
         kb = dict(block_n=min(256, _round8(B)), block_q=_round8(c),
                   block_d=min(128, _round8(d1)))
@@ -356,7 +358,8 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
         ok = valid[None, :] if rowhit is None else (valid[None, :] & rowhit)
         enter = ok & (tau_k >= 0.0)[:, None]                  # (c, B)
         contrib0 = jnp.maximum(
-            lsg[0][None, :] - 2.0 * qlg[0] @ xg[0].T
+            lsg[0][None, :]
+            - 2.0 * jnp.matmul(qlg[0], xg[0].T, precision=HIGHEST)
             + qgsq[0][:, None], 0.0)                          # (c, B)
         dims_b = enter.sum(-1).astype(jnp.float32) * jnp.float32(gw[0])
         if cfg.kind == "ddcres":
@@ -385,7 +388,8 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             xc = xg[g][cand]                                  # (c, Rp, dg)
             contrib = jnp.maximum(
                 lsg[g][cand]
-                - 2.0 * jnp.einsum("cd,crd->cr", qlg[g], xc)
+                - 2.0 * jnp.einsum("cd,crd->cr", qlg[g], xc,
+                                   precision=HIGHEST)
                 + qgsq[g][:, None], 0.0)
             acc = jnp.where(aliveR, acc + contrib, acc)
         if cfg.kind == "ddcres":
@@ -426,7 +430,9 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
         if partial is None:       # opq / PDX escape: lead recomputed in full
             partial = _lead_partial(blk)
         exact = partial + jnp.maximum(
-            blk["tsq"][None, :] - 2.0 * qt @ blk["xt"].T + qt_sq[:, None], 0.0)
+            blk["tsq"][None, :]
+            - 2.0 * jnp.matmul(qt, blk["xt"].T, precision=HIGHEST)
+            + qt_sq[:, None], 0.0)
         exact = jnp.where(ok, exact, jnp.inf)
         new_d, new_i = _merge_topk(
             best_d, best_i, exact,
@@ -493,7 +499,8 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             dims_scr = ad.sum(0)        # measured dims entered per query
         else:
             partial = jnp.maximum(
-                blk["lsq"][None, :] - 2.0 * ql @ blk["xl"].T
+                blk["lsq"][None, :]
+                - 2.0 * jnp.matmul(ql, blk["xl"].T, precision=HIGHEST)
                 + (ql ** 2).sum(1)[:, None], 0.0)             # (c, B)
             est = partial * scale
             keep = (est <= tau_k[:, None]) & valid[None, :]
@@ -515,7 +522,8 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
 
         if cfg.kind == "fdscan":
             exact = partial + jnp.maximum(
-                blk["tsq"][None, :] - 2.0 * qt @ blk["xt"].T
+                blk["tsq"][None, :]
+                - 2.0 * jnp.matmul(qt, blk["xt"].T, precision=HIGHEST)
                 + qt_sq[:, None], 0.0)
             ok = okm
             exact = jnp.where(ok, exact, jnp.inf)
@@ -553,11 +561,13 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             acc = jnp.zeros((c, xl.shape[-2]), jnp.float32)
             for g in range(Gr):
                 acc = acc + jnp.maximum(
-                    blk["lsg"][g][None, :] - 2.0 * qlg[g] @ xl[g].T
+                    blk["lsg"][g][None, :]
+                    - 2.0 * jnp.matmul(qlg[g], xl[g].T, precision=HIGHEST)
                     + qgsq[g][:, None], 0.0)
             return acc
         return jnp.maximum(
-            blk["lsq"][None, :] - 2.0 * ql @ xl.T
+            blk["lsq"][None, :]
+            - 2.0 * jnp.matmul(ql, xl.T, precision=HIGHEST)
             + (ql ** 2).sum(1)[:, None], 0.0)                 # (c, B)
 
     def _screen_of(partial, blk, tau, ok):
@@ -716,7 +726,8 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
                 rowhit = (blk["part"][None, :, None] == pr[:, None, :]).any(-1)
                 ok = valid[None, :] & rowhit
             exact = _lead_partial(blk) + jnp.maximum(
-                blk["tsq"][None, :] - 2.0 * qt @ blk["xt"].T
+                blk["tsq"][None, :]
+                - 2.0 * jnp.matmul(qt, blk["xt"].T, precision=HIGHEST)
                 + qt_sq[:, None], 0.0)
             exact = jnp.where(ok, exact, jnp.inf)
             nd, ni = _merge_topk(
@@ -831,14 +842,17 @@ def _seed_eval(state: dict, xs: dict, q_lead, q_tail, q_extra: dict,
         lead_s = jnp.zeros((ql.shape[0], S), jnp.float32)
         for g in range(Gg):
             lead_s = lead_s + jnp.maximum(
-                xs["lsg"][0][g, :S][None, :] - 2.0 * qg[g] @ xl0[g, :S].T
+                xs["lsg"][0][g, :S][None, :]
+                - 2.0 * jnp.matmul(qg[g], xl0[g, :S].T, precision=HIGHEST)
                 + (qg[g] ** 2).sum(1)[:, None], 0.0)
     else:
         lead_s = jnp.maximum(
-            xs["lsq"][0, :S][None, :] - 2.0 * ql @ xl0[:S].T
+            xs["lsq"][0, :S][None, :]
+            - 2.0 * jnp.matmul(ql, xl0[:S].T, precision=HIGHEST)
             + (ql ** 2).sum(1)[:, None], 0.0)
     ex = lead_s + jnp.maximum(
-        xs["tsq"][0, :S][None, :] - 2.0 * qt @ xs["xt"][0, :S].T
+        xs["tsq"][0, :S][None, :]
+        - 2.0 * jnp.matmul(qt, xs["xt"][0, :S].T, precision=HIGHEST)
         + (qt ** 2).sum(1)[:, None], 0.0)
     ex = jnp.where(svalid, ex, jnp.inf)
     neg, _ = jax.lax.top_k(-ex, min(cfg.k, S))

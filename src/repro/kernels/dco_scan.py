@@ -21,17 +21,28 @@ Two entry points share one kernel body:
                         when candidates freeze between groups.
 
 Outputs, per call:
-  partial (N, Q) f32   running partial distances (frozen rows keep the value
-                       at which they were pruned);
-  keep    (N, Q) int8  1 iff the final scaled estimate still clears tau AND
-                       the row index is < ``nrows`` (padding rows never keep);
-  counts  (NB, Q) i32  per-candidate-block keep counts (NB = N / block_n) —
-                       what the streaming engine (core.stream_engine) consumes
-                       so no (N, Q) array ever has to leave the block loop;
-  dims    (NB, Q) f32  dimensions actually entered per candidate block: each
-                       dim block adds ``widths[di]`` for every still-alive
-                       valid row — the measured early-exit telemetry behind
-                       the facade's ``dims_read_mean`` stat.
+  partial (N, Q) f32      running partial distances (frozen rows keep the
+                          value at which they were pruned);
+  keep    (N, Q) int8     1 iff the final scaled estimate still clears tau
+                          AND the row index is < ``nrows`` (padding rows
+                          never keep);
+  counts  (NB, 8, Q) i32  per-candidate-block keep counts (NB = N / block_n)
+                          — what the streaming engine (core.stream_engine)
+                          consumes so no (N, Q) array ever has to leave the
+                          block loop;
+  dims    (NB, 8, Q) f32  dimensions actually entered per candidate block:
+                          each dim block adds ``widths[di]`` for every
+                          still-alive valid row — the measured early-exit
+                          telemetry behind the facade's ``dims_read_mean``.
+  The per-block totals are replicated over an 8-row sublane axis: Mosaic
+  tiles the last two block dims in (8, 128) units, so a (1, BQ) block of an
+  (NB, Q) array does not lower.  ``kernels.ops`` returns row 0 as (NB, Q).
+
+The per-dim-block scalars (``scales``, ``widths``) and ``nrows`` live in
+SMEM: they are read at a grid-dependent index, which a VMEM vector block
+cannot serve.  The cross term runs at ``Precision.HIGHEST``: the partial is
+a certified lower bound only if it is computed in f32, and a default TPU
+f32 dot is one bf16 pass.
 
 Tile sizes: x tile (BN, BD), q tile (BQ, BD), accumulator (BN, BQ) — all
 MXU-aligned multiples of (8, 128) for f32.
@@ -43,6 +54,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: sublane rows the per-block ``counts``/``dims`` totals are replicated over
+SUBLANES = 8
 
 
 def _kernel(scales_ref, widths_ref, nrows_ref, x_ref, q_ref, tau_ref,
@@ -64,7 +79,8 @@ def _kernel(scales_ref, widths_ref, nrows_ref, x_ref, q_ref, tau_ref,
     # width (0 for shape-padding dim blocks), whether or not the tile-level
     # skip below saves the matmul — per-row exit is what the stat measures
     entering = (alive & (row < nrows_ref[0])).astype(jnp.float32)
-    dims_ref[...] += entering.sum(0, keepdims=True) * widths_ref[di]
+    dims_ref[...] += jnp.broadcast_to(
+        entering.sum(0, keepdims=True) * widths_ref[di], dims_ref.shape)
 
     @pl.when(jnp.any(alive))
     def _compute():
@@ -75,6 +91,7 @@ def _kernel(scales_ref, widths_ref, nrows_ref, x_ref, q_ref, tau_ref,
         contrib = ((xb * xb).sum(1, keepdims=True)
                    - 2.0 * jax.lax.dot_general(
                        xb, qb, (((1,), (1,)), ((), ())),
+                       precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
                    + (qb * qb).sum(1, keepdims=True).T)
         out_ref[...] = jnp.where(alive, out_ref[...] + jnp.maximum(contrib, 0.0),
@@ -85,15 +102,16 @@ def _kernel(scales_ref, widths_ref, nrows_ref, x_ref, q_ref, tau_ref,
         est = out_ref[...] * scales_ref[di]
         keep = alive & (est <= tau) & (row < nrows_ref[0])
         keep_ref[...] = keep.astype(jnp.int8)
-        cnt_ref[...] = keep.astype(jnp.int32).sum(0, keepdims=True)
+        cnt_ref[...] = jnp.broadcast_to(
+            keep.astype(jnp.int32).sum(0, keepdims=True), cnt_ref.shape)
 
 
 def _out_shapes(n, nq, nnb):
     return [
         jax.ShapeDtypeStruct((n, nq), jnp.float32),
         jax.ShapeDtypeStruct((n, nq), jnp.int8),
-        jax.ShapeDtypeStruct((nnb, nq), jnp.int32),
-        jax.ShapeDtypeStruct((nnb, nq), jnp.float32),
+        jax.ShapeDtypeStruct((nnb, SUBLANES, nq), jnp.int32),
+        jax.ShapeDtypeStruct((nnb, SUBLANES, nq), jnp.float32),
     ]
 
 
@@ -101,9 +119,14 @@ def _out_specs(block_n, block_q):
     return [
         pl.BlockSpec((block_n, block_q), lambda qi, ni, di: (ni, qi)),
         pl.BlockSpec((block_n, block_q), lambda qi, ni, di: (ni, qi)),
-        pl.BlockSpec((1, block_q), lambda qi, ni, di: (ni, qi)),
-        pl.BlockSpec((1, block_q), lambda qi, ni, di: (ni, qi)),
+        pl.BlockSpec((None, SUBLANES, block_q), lambda qi, ni, di: (ni, 0, qi)),
+        pl.BlockSpec((None, SUBLANES, block_q), lambda qi, ni, di: (ni, 0, qi)),
     ]
+
+
+def _scalar_specs():
+    """scales, widths, nrows: whole arrays in SMEM (dynamic scalar reads)."""
+    return [pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_q", "block_d",
@@ -114,9 +137,10 @@ def dco_scan(x, q, tau, scales, widths, nrows, *, block_n: int = 256,
     tau (Q,) squared thresholds; scales (n_dblocks,) estimate multipliers;
     widths (n_dblocks,) f32 logical dims per dim block (0 for padding
     blocks); nrows (1,) i32 count of valid (non-padding) leading rows of x.
-    Returns (partial (N, Q) f32, keep (N, Q) int8, counts (N/block_n, Q) i32,
-    dims (N/block_n, Q) f32).  N, Q, d1 must be tile multiples —
-    ``kernels.ops.dco_scan_op`` pads arbitrary shapes."""
+    Returns (partial (N, Q) f32, keep (N, Q) int8, counts (N/block_n, 8, Q)
+    i32, dims (N/block_n, 8, Q) f32; the 8 rows of a block are equal).
+    N, Q, d1 must be tile multiples — ``kernels.ops.dco_scan_op`` pads
+    arbitrary shapes."""
     n, d1 = x.shape
     nq = q.shape[0]
     nd = pl.cdiv(d1, block_d)
@@ -127,9 +151,7 @@ def dco_scan(x, q, tau, scales, widths, nrows, *, block_n: int = 256,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((scales.shape[0],), lambda qi, ni, di: (0,)),
-            pl.BlockSpec((widths.shape[0],), lambda qi, ni, di: (0,)),
-            pl.BlockSpec((1,), lambda qi, ni, di: (0,)),
+            *_scalar_specs(),
             pl.BlockSpec((block_n, block_d), lambda qi, ni, di: (ni, di)),
             pl.BlockSpec((block_q, block_d), lambda qi, ni, di: (qi, di)),
             pl.BlockSpec((block_q,), lambda qi, ni, di: (qi,)),
@@ -159,9 +181,7 @@ def dco_scan_grouped(x, q, tau, scales, widths, nrows, *, block_n: int = 256,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((scales.shape[0],), lambda qi, ni, di: (0,)),
-            pl.BlockSpec((widths.shape[0],), lambda qi, ni, di: (0,)),
-            pl.BlockSpec((1,), lambda qi, ni, di: (0,)),
+            *_scalar_specs(),
             pl.BlockSpec((1, block_n, dg), lambda qi, ni, di: (di, ni, 0)),
             pl.BlockSpec((1, block_q, dg), lambda qi, ni, di: (di, qi, 0)),
             pl.BlockSpec((block_q,), lambda qi, ni, di: (qi,)),

@@ -1,14 +1,13 @@
-"""jit'd public wrappers for the Pallas kernels: shape padding + fallbacks.
+"""jit'd public wrappers for the Pallas kernels: shape padding.
 
-``interpret`` defaults to True when no TPU is present so the same call sites
-work in this CPU container and on real hardware.  Setting
-``REPRO_FORCE_INTERPRET=1`` forces interpret mode regardless of the platform
-(CI runs the kernel parity tests under this flag as an explicit step).
+``interpret=None`` (the default) compiles the kernels with Mosaic when JAX's
+default backend is a TPU and runs them in interpret mode otherwise, so the
+same call sites serve CPU tests and the chip.  Pass ``interpret=True`` to
+force interpret mode.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,15 +18,11 @@ from repro.kernels.pq_lookup import pq_lookup
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """True when JAX's default backend is a TPU (errors propagate)."""
+    return jax.default_backend() == "tpu"
 
 
 def _resolve_interpret(interpret) -> bool:
-    if os.environ.get("REPRO_FORCE_INTERPRET", "").lower() not in ("", "0", "false"):
-        return True
     return (not _on_tpu()) if interpret is None else interpret
 
 
@@ -68,7 +63,7 @@ def dco_scan_op(x, q, tau, scales, nrows=None, *, block_n=256, block_q=128,
     partial, keep, counts, dims = dco_scan(
         xp, qp, taup, sc[:nd], jnp.asarray(w), nr, block_n=block_n,
         block_q=block_q, block_d=block_d, interpret=interpret)
-    return partial[:n, :nq], keep[:n, :nq], counts[:, :nq], dims[:, :nq]
+    return partial[:n, :nq], keep[:n, :nq], counts[:, 0, :nq], dims[:, 0, :nq]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_q", "interpret"))
@@ -91,7 +86,7 @@ def dco_scan_grouped_op(x, q, tau, scales, widths, nrows=None, *, block_n=256,
     partial, keep, counts, dims = dco_scan_grouped(
         xp, qp, taup, scales, widths.astype(jnp.float32), nr,
         block_n=block_n, block_q=block_q, interpret=interpret)
-    return partial[:n, :nq], keep[:n, :nq], counts[:, :nq], dims[:, :nq]
+    return partial[:n, :nq], keep[:n, :nq], counts[:, 0, :nq], dims[:, 0, :nq]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_q", "interpret"))

@@ -22,10 +22,8 @@ def _mesh(shape, axes):
             "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "BEFORE importing jax")
     devices = np.asarray(devs[:n]).reshape(shape)
-    if hasattr(jax.sharding, "AxisType"):      # jax >= 0.5
-        return jax.sharding.Mesh(
-            devices, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.sharding.Mesh(devices, axes)
+    return jax.sharding.Mesh(
+        devices, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

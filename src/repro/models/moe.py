@@ -177,14 +177,13 @@ def moe_forward(params, cfg, x, *, mesh=None, dp_axes=("data",),
             return out.reshape(xl.shape).astype(
                 psum_dtype or out.dtype), aux
 
-        from jax.experimental import shard_map
-        local_fn_sm = shard_map.shard_map(
+        local_fn_sm = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(dp_axes, None, None), P(None, None),
                       P(tp_axis, dp_axes, None), P(tp_axis, dp_axes, None),
                       P(tp_axis, None, dp_axes)),
             out_specs=(P(dp_axes, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )
         out, aux = local_fn_sm(x, params["router"], params["wg"],
                                params["wu"], params["wd"])

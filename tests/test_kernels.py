@@ -1,6 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps + hypothesis vs ref.py
-oracles (interpret mode on CPU; same code targets TPU).  CI also runs this
-module as an explicit interpret-mode step (REPRO_FORCE_INTERPRET=1)."""
+oracles (interpret mode on CPU; same code targets TPU, where
+tests/test_tpu_compile.py checks that it compiles)."""
 import zlib
 
 import jax.numpy as jnp

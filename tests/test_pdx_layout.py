@@ -281,8 +281,7 @@ def test_pdx_delta_segment_matches_merged():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_pdx_kernel_path_matches_jnp(monkeypatch):
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+def test_pdx_kernel_path_matches_jnp():
     X, Q = _decayed(800, 96, seed=29)
     base = _cfg(48, row_block=256, block_capacity=256, dim_groups=4)
     dj, ij, *_ = _run(X, Q, base)
