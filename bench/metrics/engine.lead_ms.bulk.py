@@ -1,0 +1,11 @@
+"""Streaming engine (``core/stream_engine.py``): own device time of the
+operations under the ``dco.lead`` scope (stage-1 lead distances and the
+screen: the ``dco_scan`` kernel or the jnp lead product) per whole
+``bench.step`` span of the traced stretch, mean, in milliseconds
+(``bench.stages.scope_ms``).  Device trace.  None where the trace carries
+no such scope."""
+from bench import stages
+
+
+def read(ctx):
+    return stages.scope_ms(ctx.trace, "dco.lead")

@@ -1,0 +1,10 @@
+"""Streaming engine (``core/stream_engine.py``): own device time of the
+operations under the ``dco.merge`` scope (the running top-k merge and the
+tau update) per whole ``bench.step`` span of the traced stretch, mean, in
+milliseconds (``bench.stages.scope_ms``).  Device trace.  None where the
+trace carries no such scope."""
+from bench import stages
+
+
+def read(ctx):
+    return stages.scope_ms(ctx.trace, "dco.merge")

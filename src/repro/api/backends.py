@@ -25,6 +25,7 @@ from repro.core.engine import (EXTRA_COVERAGE, EXTRA_DIMS_READ_MEAN,
                                ScanStats, scan_topk)
 from repro.core.policy import PolicyConfig, finalize_adaptive_extra
 from repro.testing import faults
+from repro.utils.spans import span
 
 
 def _arm_guardrail(method, index_kind: str, policy, backend: str):
@@ -572,29 +573,30 @@ class JaxBackend:
 
         if self._dstate is None:
             self._materialize()
-        t_end = None
-        if deadline_s is not None:
-            if self.mesh is not None:
-                raise ValueError(
-                    "anytime deadlines are single-device (the mesh scan has "
-                    "no per-group host sync to check the clock at; "
-                    "DESIGN.md §7)")
-            t_end = time.monotonic() + float(deadline_s)
-        cfg = self._config(k, anytime=t_end is not None, demoted=demoted)
-        ql, qt, qe = self._prep_queries(Q)
+        with span("search.prep"):
+            t_end = None
+            if deadline_s is not None:
+                if self.mesh is not None:
+                    raise ValueError(
+                        "anytime deadlines are single-device (the mesh scan "
+                        "has no per-group host sync to check the clock at; "
+                        "DESIGN.md §7)")
+                t_end = time.monotonic() + float(deadline_s)
+            cfg = self._config(k, anytime=t_end is not None, demoted=demoted)
+            ql, qt, qe = self._prep_queries(Q)
+            qe = {key: jnp.asarray(v) for key, v in qe.items()}
+            ql, qt = jnp.asarray(ql), jnp.asarray(qt)
         nq, N, D = ql.shape[0], self.method.state["N"], self.method.state["D"]
         engine = self.policy.engine
         if (cfg.kind == "opq" or self.index_kind == "ivf"
                 or cfg.policy is not None or t_end is not None):
             engine = "stream"       # only the streaming engine serves these
-        qe = {key: jnp.asarray(v) for key, v in qe.items()}
         cand_per_q = np.full(nq, N, np.float64)
         passed = dmin = report = coverage = dims_read = None
         n_anchor = 0                # two_stage completes k anchors per query
         if self.mesh is None:
             if engine == "two_stage":
-                out = two_stage_topk(
-                    self._state, jnp.asarray(ql), jnp.asarray(qt), cfg, qe)
+                out = two_stage_topk(self._state, ql, qt, cfg, qe)
                 n_anchor = nq * k
             else:
                 from repro.core.stream_engine import build_stream_blocks
@@ -626,13 +628,14 @@ class JaxBackend:
                             self._delta_parts[None, :nd, None]
                             == probed[:, None, :]).any(-1).sum(1)
                 out = stream_topk(
-                    st, jnp.asarray(ql), jnp.asarray(qt), cfg, qe,
-                    probe, blocks=blocks, deadline_ts=t_end,
+                    st, ql, qt, cfg, qe, probe, blocks=blocks,
+                    deadline_ts=t_end,
                     block_group=self.policy.anytime_block_group)
             # one batched transfer: the post-jit slices (and the adaptive
             # report) are tiny lazy dispatches — converting them one
             # np.asarray at a time serializes a sync per output
-            out = jax.device_get(out)
+            with span("search.fetch"):
+                out = jax.device_get(out)
             if engine == "two_stage":
                 d, i, surv = out
             elif cfg.policy is not None:
@@ -654,63 +657,64 @@ class JaxBackend:
                                           engine=engine,
                                           n_rows=self._n_main))
             d, i, surv, dmin = self._mesh_fns[cfg](*self._shard_args,
-                                                   jnp.asarray(ql),
-                                                   jnp.asarray(qt), qe)
-            surv = np.asarray(surv)     # real completions, psum'd over shards
+                                                   ql, qt, qe)
+            with span("search.fetch"):
+                surv = np.asarray(surv)     # real completions, psum'd
+                jax.block_until_ready(d)
             if engine == "two_stage":
                 n_anchor = nq * k * int(np.prod(tuple(self.mesh.shape.values())))
-        jax.block_until_ready(d)
-        stats = ScanStats(n_dco=int(cand_per_q.sum()),
-                          dims_total=float((cand_per_q * D).sum()))
-        if cfg.kind == "fdscan":
-            stats.dims_scanned = stats.dims_total
-        elif cfg.kind == "opq":
-            # PQ screening charges n_sub 'dims' per candidate (as the host
-            # rule does); survivors complete the full D original dims
-            n_sub = int(self._dstate["books"].shape[0])
-            stats.dims_scanned = (float((cand_per_q * n_sub).sum())
-                                  + float(surv.sum()) * D)
-            stats.extra[EXTRA_SURVIVORS_MEAN] = float(surv.mean())
-            stats.extra[EXTRA_SCREEN_PASS_MEAN] = float(np.asarray(passed).mean())
-            self._certify(stats, d, dmin)
-        else:
-            # stage 1 streams d1 dims for every candidate row; stage 2 (plus
-            # the two-stage engine's k anchor completions) streams the tail
-            # for the ACTUAL survivors
-            stats.dims_scanned = (float((cand_per_q * self._d1).sum())
-                                  + float(surv.sum() + n_anchor) * (D - self._d1))
-            stats.extra[EXTRA_SURVIVORS_MEAN] = float(surv.mean())
-            if passed is not None:
+        with span("search.finish"):
+            stats = ScanStats(n_dco=int(cand_per_q.sum()),
+                              dims_total=float((cand_per_q * D).sum()))
+            if cfg.kind == "fdscan":
+                stats.dims_scanned = stats.dims_total
+            elif cfg.kind == "opq":
+                # PQ screening charges n_sub 'dims' per candidate (as the host
+                # rule does); survivors complete the full D original dims
+                n_sub = int(self._dstate["books"].shape[0])
+                stats.dims_scanned = (float((cand_per_q * n_sub).sum())
+                                      + float(surv.sum()) * D)
+                stats.extra[EXTRA_SURVIVORS_MEAN] = float(surv.mean())
                 stats.extra[EXTRA_SCREEN_PASS_MEAN] = float(np.asarray(passed).mean())
-            self._certify(stats, d, dmin)
-        if dims_read is not None:
-            # the streaming scan measured its own reads (per-group alive
-            # counts + completed tails, DESIGN.md §8): trust them over the
-            # stage-shaped formula — under PDX early exit the formula
-            # overstates lead reads, under adaptive fallback it understates
-            stats.dims_scanned = float(
-                np.asarray(dims_read, np.float64).sum())
-        stats.extra[EXTRA_DIMS_READ_MEAN] = (
-            stats.dims_scanned / max(stats.n_dco, 1))
-        if report is not None:
-            stats.extra[EXTRA_FALLBACK_BLOCKS] = float(
-                np.asarray(report["fallback_blocks"]).mean())
-            stats.extra[EXTRA_EST_SAVED_FLOPS] = float(
-                np.asarray(report["est_saved_flops"]).sum())
-            stats.extra[EXTRA_RULE_TIMELINE] = [
-                float(v) for v in np.asarray(report["rule_timeline"])]
-        # anytime coverage (DESIGN.md §7): every query of the batch shares
-        # the scanned-block fraction; partial scans are uncertified even if
-        # the dropped-estimate certificate held over the scanned prefix
-        cov_arr = np.full(nq, 1.0 if coverage is None else coverage,
-                          np.float32)
-        stats.extra[EXTRA_COVERAGE] = cov_arr
-        mask = stats.extra.get(EXTRA_UNCERTIFIED_MASK)
-        if mask is not None and coverage is not None and coverage < 1.0:
-            stats.extra[EXTRA_UNCERTIFIED_MASK] = mask | (cov_arr < 1.0)
-            stats.extra[EXTRA_UNCERTIFIED_QUERIES] = float(
-                stats.extra[EXTRA_UNCERTIFIED_MASK].mean())
-        return (np.asarray(d, np.float32), np.asarray(i, np.int64), stats)
+                self._certify(stats, d, dmin)
+            else:
+                # stage 1 streams d1 dims for every candidate row; stage 2 (plus
+                # the two-stage engine's k anchor completions) streams the tail
+                # for the ACTUAL survivors
+                stats.dims_scanned = (float((cand_per_q * self._d1).sum())
+                                      + float(surv.sum() + n_anchor) * (D - self._d1))
+                stats.extra[EXTRA_SURVIVORS_MEAN] = float(surv.mean())
+                if passed is not None:
+                    stats.extra[EXTRA_SCREEN_PASS_MEAN] = float(np.asarray(passed).mean())
+                self._certify(stats, d, dmin)
+            if dims_read is not None:
+                # the streaming scan measured its own reads (per-group alive
+                # counts + completed tails, DESIGN.md §8): trust them over the
+                # stage-shaped formula — under PDX early exit the formula
+                # overstates lead reads, under adaptive fallback it understates
+                stats.dims_scanned = float(
+                    np.asarray(dims_read, np.float64).sum())
+            stats.extra[EXTRA_DIMS_READ_MEAN] = (
+                stats.dims_scanned / max(stats.n_dco, 1))
+            if report is not None:
+                stats.extra[EXTRA_FALLBACK_BLOCKS] = float(
+                    np.asarray(report["fallback_blocks"]).mean())
+                stats.extra[EXTRA_EST_SAVED_FLOPS] = float(
+                    np.asarray(report["est_saved_flops"]).sum())
+                stats.extra[EXTRA_RULE_TIMELINE] = [
+                    float(v) for v in np.asarray(report["rule_timeline"])]
+            # anytime coverage (DESIGN.md §7): every query of the batch shares
+            # the scanned-block fraction; partial scans are uncertified even if
+            # the dropped-estimate certificate held over the scanned prefix
+            cov_arr = np.full(nq, 1.0 if coverage is None else coverage,
+                              np.float32)
+            stats.extra[EXTRA_COVERAGE] = cov_arr
+            mask = stats.extra.get(EXTRA_UNCERTIFIED_MASK)
+            if mask is not None and coverage is not None and coverage < 1.0:
+                stats.extra[EXTRA_UNCERTIFIED_MASK] = mask | (cov_arr < 1.0)
+                stats.extra[EXTRA_UNCERTIFIED_QUERIES] = float(
+                    stats.extra[EXTRA_UNCERTIFIED_MASK].mean())
+            return (np.asarray(d, np.float32), np.asarray(i, np.int64), stats)
 
     @staticmethod
     def _certify(stats, d, dmin):

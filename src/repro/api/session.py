@@ -21,6 +21,7 @@ from repro.api.types import SchedulePolicy, SearchResult
 from repro.core.methods import ALL_METHODS, make_method
 from repro.search.hnsw import HNSWIndex
 from repro.search.ivf import IVFIndex
+from repro.utils.spans import span
 
 INDEX_KINDS = ("flat", "ivf", "hnsw")
 #: facade name of every paper method -> backends that can serve it natively.
@@ -74,22 +75,23 @@ class SearchSession:
         set ``uncertified_mask`` bit in ``result.stats.extra``; with a
         generous deadline the result is bit-identical to the non-deadline
         path.  Flat/IVF only (HNSW walks and mesh scans reject it)."""
-        Q = np.atleast_2d(np.asarray(Q))
-        if Q.dtype.kind not in "fiu":
-            raise ValueError(
-                f"search(): expected a numeric query array, got dtype {Q.dtype}")
-        Q = np.ascontiguousarray(Q, np.float32)
-        if not np.isfinite(Q).all():
-            bad = int((~np.isfinite(Q).all(axis=1)).sum())
-            raise ValueError(
-                f"search(): {bad} of {Q.shape[0]} queries contain NaN/Inf "
-                "values; distances to non-finite queries are meaningless "
-                "and would poison the running top-k threshold")
-        if deadline_s is not None and deadline_s <= 0.0:
-            raise ValueError(
-                f"search(): deadline_s must be > 0 (got {deadline_s}); the "
-                "engines always finish at least one block group, so a "
-                "non-positive budget cannot mean 'return nothing'")
+        with span("search.prep"):
+            Q = np.atleast_2d(np.asarray(Q))
+            if Q.dtype.kind not in "fiu":
+                raise ValueError(
+                    f"search(): expected a numeric query array, got dtype {Q.dtype}")
+            Q = np.ascontiguousarray(Q, np.float32)
+            if not np.isfinite(Q).all():
+                bad = int((~np.isfinite(Q).all(axis=1)).sum())
+                raise ValueError(
+                    f"search(): {bad} of {Q.shape[0]} queries contain NaN/Inf "
+                    "values; distances to non-finite queries are meaningless "
+                    "and would poison the running top-k threshold")
+            if deadline_s is not None and deadline_s <= 0.0:
+                raise ValueError(
+                    f"search(): deadline_s must be > 0 (got {deadline_s}); the "
+                    "engines always finish at least one block group, so a "
+                    "non-positive budget cannot mean 'return nothing'")
         t0 = time.perf_counter()
         dists, ids, stats = self.backend.search(Q, k, nprobe=nprobe, ef=ef,
                                                 deadline_s=deadline_s)

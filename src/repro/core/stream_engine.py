@@ -80,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.jax_engine import HIGHEST, DcoEngineConfig
+from repro.utils.spans import span
 
 
 def _round8(v: int) -> int:
@@ -127,11 +128,17 @@ def _final_scale(cfg: DcoEngineConfig, state: dict, D: int):
     raise ValueError(cfg.kind)
 
 
-def _merge_topk(best_d, best_i, new_d, new_i, k: int):
-    d = jnp.concatenate([best_d, new_d], axis=1)
-    i = jnp.concatenate([best_i, new_i], axis=1)
-    neg, pos = jax.lax.top_k(-d, k)
-    return -neg, jnp.take_along_axis(i, pos, axis=1)
+def _merge_topk(best_d, best_i, tau, new_d, new_i, cfg: DcoEngineConfig):
+    """Fold completed rows into the running top-k and tighten tau.  min()
+    keeps a tighter seeded tau alive until the running top-k beats it;
+    without a seed the k-th only decreases, so it is a no-op."""
+    with jax.named_scope("dco.merge"):
+        d = jnp.concatenate([best_d, new_d], axis=1)
+        i = jnp.concatenate([best_i, new_i], axis=1)
+        neg, pos = jax.lax.top_k(-d, cfg.k)
+        best_d = -neg
+        return (best_d, jnp.take_along_axis(i, pos, axis=1),
+                jnp.minimum(tau, best_d[:, -1] * cfg.tau_slack))
 
 
 @functools.partial(jax.jit,
@@ -313,31 +320,34 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
 
     def _complete_screened(best_d, best_i, tau, keep, est, partial, blk):
         # ---- on-device compaction: top-C survivors by estimate ------------
-        score = jnp.where(keep, est, jnp.inf)
-        neg_s, cand = jax.lax.top_k(-score, Cp)               # (c, C [+1])
-        # Column C (when present) is the best estimate among rows the budget
-        # DROPPED: the exactness certificate — no true neighbor was lost iff
-        # the final k-th distance stays below every dropped lower bound.  It
-        # is read via a masked reduce and the extra column is disabled by
-        # masking, NOT by slicing: XLA CPU only rewrites the top_k sort into
-        # the O(n log k) TopK custom call when it feeds a single slice, and
-        # a second column slice forced a full row sort (15x slower)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, Cp), 1)
-        dropped = -jnp.max(jnp.where(col == C, neg_s, -jnp.inf), -1)
-        alive = (neg_s > -jnp.inf) & (col < C)
-        rows = jnp.arange(c)[:, None]
-        c_tail = blk["xt"][cand]                              # (c, Cp, Dt)
-        tail = jnp.maximum(((c_tail - qt[:, None, :]) ** 2).sum(-1), 0.0)
-        if cfg.kind == "opq":
-            c_lead = blk["xl"][cand]
-            exact = jnp.maximum(((c_lead - ql[:, None, :]) ** 2).sum(-1), 0.0) + tail
-        else:
-            exact = partial[rows, cand] + tail
-        exact = jnp.where(alive, exact, jnp.inf)
-        new_d, new_i = _merge_topk(best_d, best_i, exact, blk["ids"][cand], k)
-        # min() keeps a tighter seeded tau alive until the running top-k
-        # beats it; without a seed the k-th only decreases, so it's a no-op
-        new_tau = jnp.minimum(tau, new_d[:, -1] * cfg.tau_slack)
+        with jax.named_scope("dco.compact"):
+            score = jnp.where(keep, est, jnp.inf)
+            neg_s, cand = jax.lax.top_k(-score, Cp)           # (c, C [+1])
+            # Column C (when present) is the best estimate among rows the
+            # budget DROPPED: the exactness certificate — no true neighbor
+            # was lost iff the final k-th distance stays below every dropped
+            # lower bound.  It is read via a masked reduce and the extra
+            # column is disabled by masking, NOT by slicing: XLA CPU only
+            # rewrites the top_k sort into the O(n log k) TopK custom call
+            # when it feeds a single slice, and a second column slice forced
+            # a full row sort (15x slower)
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, Cp), 1)
+            dropped = -jnp.max(jnp.where(col == C, neg_s, -jnp.inf), -1)
+            alive = (neg_s > -jnp.inf) & (col < C)
+        with jax.named_scope("dco.tail"):
+            rows = jnp.arange(c)[:, None]
+            c_tail = blk["xt"][cand]                          # (c, Cp, Dt)
+            tail = jnp.maximum(((c_tail - qt[:, None, :]) ** 2).sum(-1), 0.0)
+            if cfg.kind == "opq":
+                c_lead = blk["xl"][cand]
+                exact = jnp.maximum(
+                    ((c_lead - ql[:, None, :]) ** 2).sum(-1), 0.0) + tail
+            else:
+                exact = partial[rows, cand] + tail
+            exact = jnp.where(alive, exact, jnp.inf)
+            ids = blk["ids"][cand]
+        new_d, new_i, new_tau = _merge_topk(best_d, best_i, tau, exact, ids,
+                                            cfg)
         return (new_d, new_i, new_tau,
                 alive.sum(-1).astype(jnp.int32), dropped)
 
@@ -408,18 +418,21 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
         gathering block rows through ``cand``; the R-cut's observed drop
         folds into the returned certificate value."""
         CpR = min(C + 1, Rp)
-        score = jnp.where(keep, est, jnp.inf)
-        neg_s, sel = jax.lax.top_k(-score, CpR)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, CpR), 1)
-        droppedC = -jnp.max(jnp.where(col == C, neg_s, -jnp.inf), -1)
-        alive = (neg_s > -jnp.inf) & (col < C)
-        rsel = jnp.take_along_axis(cand, sel, axis=1)         # (c, CpR)
-        c_tail = blk["xt"][rsel]                              # (c, CpR, Dt)
-        tail = jnp.maximum(((c_tail - qt[:, None, :]) ** 2).sum(-1), 0.0)
-        exact = jnp.take_along_axis(acc, sel, axis=1) + tail
-        exact = jnp.where(alive, exact, jnp.inf)
-        new_d, new_i = _merge_topk(best_d, best_i, exact, blk["ids"][rsel], k)
-        new_tau = jnp.minimum(tau, new_d[:, -1] * cfg.tau_slack)
+        with jax.named_scope("dco.compact"):
+            score = jnp.where(keep, est, jnp.inf)
+            neg_s, sel = jax.lax.top_k(-score, CpR)
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, CpR), 1)
+            droppedC = -jnp.max(jnp.where(col == C, neg_s, -jnp.inf), -1)
+            alive = (neg_s > -jnp.inf) & (col < C)
+            rsel = jnp.take_along_axis(cand, sel, axis=1)     # (c, CpR)
+        with jax.named_scope("dco.tail"):
+            c_tail = blk["xt"][rsel]                          # (c, CpR, Dt)
+            tail = jnp.maximum(((c_tail - qt[:, None, :]) ** 2).sum(-1), 0.0)
+            exact = jnp.take_along_axis(acc, sel, axis=1) + tail
+            exact = jnp.where(alive, exact, jnp.inf)
+            ids = blk["ids"][rsel]
+        new_d, new_i, new_tau = _merge_topk(best_d, best_i, tau, exact, ids,
+                                            cfg)
         return (new_d, new_i, new_tau, alive.sum(-1).astype(jnp.int32),
                 jnp.minimum(dropped0, droppedC))
 
@@ -428,49 +441,28 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
         # all D dims, so nothing is dropped (dropped = +inf) and the
         # per-query exactness certificate is preserved by construction
         if partial is None:       # opq / PDX escape: lead recomputed in full
-            partial = _lead_partial(blk)
-        exact = partial + jnp.maximum(
-            blk["tsq"][None, :]
-            - 2.0 * jnp.matmul(qt, blk["xt"].T, precision=HIGHEST)
-            + qt_sq[:, None], 0.0)
-        exact = jnp.where(ok, exact, jnp.inf)
-        new_d, new_i = _merge_topk(
-            best_d, best_i, exact,
-            jnp.broadcast_to(blk["ids"][None, :], (c, B)), k)
-        new_tau = jnp.minimum(tau, new_d[:, -1] * cfg.tau_slack)
+            with jax.named_scope("dco.lead"):
+                partial = _lead_partial(blk)
+        new_d, new_i, new_tau = _merge_topk(
+            best_d, best_i, tau, _complete_full(blk, partial, ok),
+            jnp.broadcast_to(blk["ids"][None, :], (c, B)), cfg)
         return (new_d, new_i, new_tau, ok.sum(-1).astype(jnp.int32),
                 jnp.full((c,), jnp.inf, jnp.float32))
 
-    def step(carry, blk):
-        best_d, best_i, tau, surv, passed, dims = carry
-        valid = blk["ids"] >= 0                               # (B,)
-        rowhit = None
-        tau_k = jnp.full((c,), jnp.inf) if cfg.kind == "fdscan" else tau
-        if cfg.kind == "ddcres":
-            # partial <= tau_k is implied by the Eq. 7 estimate test below
-            tau_k = tau + slack - qe["qtail_sq"] - tail_min
-        if pr is not None:
-            # block-level probe gate: partition-major rows mean each block
-            # spans [pmin, pmax]; unprobed blocks get tau=-1, which the
-            # kernel's pl.when(any(alive)) turns into skipped matmuls
-            pmin, pmax = blk["part"].min(), blk["part"].max()
-            hit = ((pr >= pmin) & (pr <= pmax)).any(-1)       # (c,)
-            tau_k = jnp.where(hit, tau_k, -1.0)
-            rowhit = (blk["part"][None, :, None] == pr[:, None, :]).any(-1)
-        okm = valid[None, :] if rowhit is None else (valid[None, :] & rowhit)
-        n_okq = okm.sum(-1).astype(jnp.float32)               # (c,)
+    def _complete_full(blk, partial, ok):
+        """Exact distances of every row of the block: the lead partial plus
+        the full-scan tail product; rows outside ``ok`` read +inf."""
+        with jax.named_scope("dco.tail"):
+            exact = partial + jnp.maximum(
+                blk["tsq"][None, :]
+                - 2.0 * jnp.matmul(qt, blk["xt"].T, precision=HIGHEST)
+                + qt_sq[:, None], 0.0)
+            return jnp.where(ok, exact, jnp.inf)
 
-        if grouped and not cfg.use_kernel:
-            # PDX progressive refinement on the jnp path (DESIGN.md §8)
-            cand, acc, keepR, estR, dropped0, dims_scr = _pdx_screen(
-                blk, tau, tau_k, valid, rowhit)
-            passed_b = keepR.sum(-1).astype(jnp.int32)
-            new_d, new_i, new_tau, completed, dropped = _complete_compacted(
-                best_d, best_i, tau, keepR, estR, acc, cand, dropped0, blk)
-            dims_b = dims_scr + completed.astype(jnp.float32) * (D - d1)
-            return ((new_d, new_i, new_tau, surv + completed,
-                     passed + passed_b, dims + dims_b), dropped)
-
+    def _lead_screen(blk, tau, tau_k, valid, rowhit, n_okq):
+        """Stage 1 of a flat block: the lead partial (None for opq, which
+        screens on the PQ adist), the estimate, the keep mask, the passed
+        count and the screen's dims read per query."""
         passed_b = None
         if cfg.kind == "opq":
             if cfg.use_kernel:
@@ -519,18 +511,48 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             passed_b = None
         if passed_b is None:
             passed_b = keep.sum(-1).astype(jnp.int32)
+        return partial, est, keep, passed_b, dims_scr
+
+    def step(carry, blk):
+        best_d, best_i, tau, surv, passed, dims = carry
+        valid = blk["ids"] >= 0                               # (B,)
+        rowhit = None
+        tau_k = jnp.full((c,), jnp.inf) if cfg.kind == "fdscan" else tau
+        if cfg.kind == "ddcres":
+            # partial <= tau_k is implied by the Eq. 7 estimate test below
+            tau_k = tau + slack - qe["qtail_sq"] - tail_min
+        if pr is not None:
+            # block-level probe gate: partition-major rows mean each block
+            # spans [pmin, pmax]; unprobed blocks get tau=-1, which the
+            # kernel's pl.when(any(alive)) turns into skipped matmuls
+            pmin, pmax = blk["part"].min(), blk["part"].max()
+            hit = ((pr >= pmin) & (pr <= pmax)).any(-1)       # (c,)
+            tau_k = jnp.where(hit, tau_k, -1.0)
+            rowhit = (blk["part"][None, :, None] == pr[:, None, :]).any(-1)
+        okm = valid[None, :] if rowhit is None else (valid[None, :] & rowhit)
+        n_okq = okm.sum(-1).astype(jnp.float32)               # (c,)
+
+        if grouped and not cfg.use_kernel:
+            # PDX progressive refinement on the jnp path (DESIGN.md §8)
+            with jax.named_scope("dco.lead"):
+                cand, acc, keepR, estR, dropped0, dims_scr = _pdx_screen(
+                    blk, tau, tau_k, valid, rowhit)
+            passed_b = keepR.sum(-1).astype(jnp.int32)
+            new_d, new_i, new_tau, completed, dropped = _complete_compacted(
+                best_d, best_i, tau, keepR, estR, acc, cand, dropped0, blk)
+            dims_b = dims_scr + completed.astype(jnp.float32) * (D - d1)
+            return ((new_d, new_i, new_tau, surv + completed,
+                     passed + passed_b, dims + dims_b), dropped)
+
+        with jax.named_scope("dco.lead"):
+            partial, est, keep, passed_b, dims_scr = _lead_screen(
+                blk, tau, tau_k, valid, rowhit, n_okq)
 
         if cfg.kind == "fdscan":
-            exact = partial + jnp.maximum(
-                blk["tsq"][None, :]
-                - 2.0 * jnp.matmul(qt, blk["xt"].T, precision=HIGHEST)
-                + qt_sq[:, None], 0.0)
-            ok = okm
-            exact = jnp.where(ok, exact, jnp.inf)
-            new_d, new_i = _merge_topk(
-                best_d, best_i, exact,
-                jnp.broadcast_to(blk["ids"][None, :], (c, B)), k)
-            n_done = ok.sum(-1).astype(jnp.int32)
+            new_d, new_i, _ = _merge_topk(
+                best_d, best_i, tau, _complete_full(blk, partial, okm),
+                jnp.broadcast_to(blk["ids"][None, :], (c, B)), cfg)
+            n_done = okm.sum(-1).astype(jnp.int32)
             new_tau = jnp.full((c,), jnp.inf)
             return ((new_d, new_i, new_tau, surv + n_done, passed + n_done,
                      dims + n_okq * float(D)),
@@ -614,8 +636,9 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             # cond boundary.
             tau_ka = (tau + slack - qe["qtail_sq"] - tail_min
                       if cfg.kind == "ddcres" else tau)
-            cand, acc, keepR, estR, dropped0, dims_scr = _pdx_screen(
-                blk, tau, tau_ka, valid, rowhit)
+            with jax.named_scope("dco.lead"):
+                cand, acc, keepR, estR, dropped0, dims_scr = _pdx_screen(
+                    blk, tau, tau_ka, valid, rowhit)
             passed_b = keepR.sum(-1).astype(jnp.int32)
             spill = (q_okm & ((passed_b > C) | ~jnp.isinf(dropped0))).any()
             esc = spill | ps["mode"]
@@ -628,9 +651,10 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
                 esc, dims_scr + nokf * float(D),
                 dims_scr + completed.astype(jnp.float32) * float(D - d1))
         else:
-            partial = None if cfg.kind == "opq" else _lead_partial(blk)
-            est, keep = _screen_of(partial, blk, tau, ok)
-            passed_b = keep.sum(-1).astype(jnp.int32)
+            with jax.named_scope("dco.lead"):
+                partial = None if cfg.kind == "opq" else _lead_partial(blk)
+                est, keep = _screen_of(partial, blk, tau, ok)
+                passed_b = keep.sum(-1).astype(jnp.int32)
             spill = (q_okm & (passed_b > C)).any()
             esc = spill | ps["mode"]
             # both completions live INSIDE the cond so an escaped block
@@ -693,7 +717,8 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
     if pol is None:
         if init_carry is not None:
             init = init_carry
-        carry, dropped = jax.lax.scan(step, init, xs)
+        with jax.named_scope("dco.scan"):
+            carry, dropped = jax.lax.scan(step, init, xs)
         if return_carry:
             return carry, dropped.min(0)
         d, i, _, surv, passed, dims = carry
@@ -725,20 +750,18 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             else:
                 rowhit = (blk["part"][None, :, None] == pr[:, None, :]).any(-1)
                 ok = valid[None, :] & rowhit
-            exact = _lead_partial(blk) + jnp.maximum(
-                blk["tsq"][None, :]
-                - 2.0 * jnp.matmul(qt, blk["xt"].T, precision=HIGHEST)
-                + qt_sq[:, None], 0.0)
-            exact = jnp.where(ok, exact, jnp.inf)
-            nd, ni = _merge_topk(
-                best_d, best_i, exact,
-                jnp.broadcast_to(blk["ids"][None, :], (c, B)), k)
-            ntau = jnp.minimum(tau, nd[:, -1] * cfg.tau_slack)
+            with jax.named_scope("dco.lead"):
+                partial = _lead_partial(blk)
+            nd, ni, ntau = _merge_topk(
+                best_d, best_i, tau, _complete_full(blk, partial, ok),
+                jnp.broadcast_to(blk["ids"][None, :], (c, B)), cfg)
             n_ok = ok.sum(-1).astype(jnp.int32)
             return (nd, ni, ntau, surv + n_ok, passed + n_ok,
                     dims + n_ok.astype(jnp.float32) * float(D)), None
 
-        (d, i, _, surv, passed, dims), _ = jax.lax.scan(step_full, init, xs)
+        with jax.named_scope("dco.scan"):
+            (d, i, _, surv, passed, dims), _ = jax.lax.scan(step_full, init,
+                                                            xs)
         report = {"fb": jnp.full((c,), nb, jnp.int32),
                   "saved": jnp.zeros((c,), jnp.float32),
                   "timeline": jnp.ones((nb,), jnp.float32)}
@@ -749,8 +772,9 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
                    "mode": jnp.asarray(False),
                    "fb": jnp.asarray(0, jnp.int32),
                    "saved": jnp.zeros((c,), jnp.float32)},)
-    (d, i, _, surv, passed, dims, ps), (dropped, modes) = jax.lax.scan(
-        step_adaptive, ini, xs)
+    with jax.named_scope("dco.scan"):
+        (d, i, _, surv, passed, dims, ps), (dropped, modes) = jax.lax.scan(
+            step_adaptive, ini, xs)
     report = {"fb": jnp.broadcast_to(ps["fb"], (c,)),
               "saved": ps["saved"], "timeline": modes}
     return d, i, surv, passed, dropped.min(0), dims, report
@@ -826,48 +850,49 @@ def _seed_eval(state: dict, xs: dict, q_lead, q_tail, q_extra: dict,
     blocks under the spill gate (k/S * row_block << block_capacity).
     Returns (tau0 (nq,), ewma0 (nq,)).
     """
-    B = xs["xl"].shape[-2]
-    D = q_lead.shape[1] + q_tail.shape[1]
-    S = min(1024, B)
-    ql, qt = q_lead, q_tail
-    sid = xs["ids"][0, :S]
-    svalid = sid[None, :] >= 0
-    xl0 = xs["xl"][0]
-    if xl0.ndim == 3:               # PDX grouped layout (DESIGN.md §8)
-        Gg, dgp = xl0.shape[0], xl0.shape[2]
-        d1 = ql.shape[1]
-        qg = jnp.moveaxis(
-            jnp.pad(ql, ((0, 0), (0, Gg * dgp - d1))).reshape(
-                ql.shape[0], Gg, dgp), 1, 0)
-        lead_s = jnp.zeros((ql.shape[0], S), jnp.float32)
-        for g in range(Gg):
-            lead_s = lead_s + jnp.maximum(
-                xs["lsg"][0][g, :S][None, :]
-                - 2.0 * jnp.matmul(qg[g], xl0[g, :S].T, precision=HIGHEST)
-                + (qg[g] ** 2).sum(1)[:, None], 0.0)
-    else:
-        lead_s = jnp.maximum(
-            xs["lsq"][0, :S][None, :]
-            - 2.0 * jnp.matmul(ql, xl0[:S].T, precision=HIGHEST)
-            + (ql ** 2).sum(1)[:, None], 0.0)
-    ex = lead_s + jnp.maximum(
-        xs["tsq"][0, :S][None, :]
-        - 2.0 * jnp.matmul(qt, xs["xt"][0, :S].T, precision=HIGHEST)
-        + (qt ** 2).sum(1)[:, None], 0.0)
-    ex = jnp.where(svalid, ex, jnp.inf)
-    neg, _ = jax.lax.top_k(-ex, min(cfg.k, S))
-    tau0 = -neg[:, -1] * cfg.tau_slack
-    if cfg.kind == "opq":           # opq evidence needs adist: stay neutral
-        return tau0, jnp.zeros(ql.shape[0], jnp.float32)
-    if cfg.kind == "ddcres":
-        slack = 2.0 * cfg.m * jnp.sqrt(jnp.maximum(q_extra["var_d1"], 0.0))
-        est_s = (lead_s + xs["tsq"][0, :S][None, :]
-                 + q_extra["qtail_sq"][:, None] - slack[:, None])
-    else:
-        est_s = lead_s * _final_scale(cfg, state, D)
-    pass_s = ((est_s <= tau0[:, None]) & svalid).sum(-1)
-    ewma0 = (pass_s / jnp.maximum(svalid.sum(-1), 1)).astype(jnp.float32)
-    return tau0, ewma0
+    with jax.named_scope("dco.seed"):
+        B = xs["xl"].shape[-2]
+        D = q_lead.shape[1] + q_tail.shape[1]
+        S = min(1024, B)
+        ql, qt = q_lead, q_tail
+        sid = xs["ids"][0, :S]
+        svalid = sid[None, :] >= 0
+        xl0 = xs["xl"][0]
+        if xl0.ndim == 3:               # PDX grouped layout (DESIGN.md §8)
+            Gg, dgp = xl0.shape[0], xl0.shape[2]
+            d1 = ql.shape[1]
+            qg = jnp.moveaxis(
+                jnp.pad(ql, ((0, 0), (0, Gg * dgp - d1))).reshape(
+                    ql.shape[0], Gg, dgp), 1, 0)
+            lead_s = jnp.zeros((ql.shape[0], S), jnp.float32)
+            for g in range(Gg):
+                lead_s = lead_s + jnp.maximum(
+                    xs["lsg"][0][g, :S][None, :]
+                    - 2.0 * jnp.matmul(qg[g], xl0[g, :S].T, precision=HIGHEST)
+                    + (qg[g] ** 2).sum(1)[:, None], 0.0)
+        else:
+            lead_s = jnp.maximum(
+                xs["lsq"][0, :S][None, :]
+                - 2.0 * jnp.matmul(ql, xl0[:S].T, precision=HIGHEST)
+                + (ql ** 2).sum(1)[:, None], 0.0)
+        ex = lead_s + jnp.maximum(
+            xs["tsq"][0, :S][None, :]
+            - 2.0 * jnp.matmul(qt, xs["xt"][0, :S].T, precision=HIGHEST)
+            + (qt ** 2).sum(1)[:, None], 0.0)
+        ex = jnp.where(svalid, ex, jnp.inf)
+        neg, _ = jax.lax.top_k(-ex, min(cfg.k, S))
+        tau0 = -neg[:, -1] * cfg.tau_slack
+        if cfg.kind == "opq":           # opq evidence needs adist: stay neutral
+            return tau0, jnp.zeros(ql.shape[0], jnp.float32)
+        if cfg.kind == "ddcres":
+            slack = 2.0 * cfg.m * jnp.sqrt(jnp.maximum(q_extra["var_d1"], 0.0))
+            est_s = (lead_s + xs["tsq"][0, :S][None, :]
+                     + q_extra["qtail_sq"][:, None] - slack[:, None])
+        else:
+            est_s = lead_s * _final_scale(cfg, state, D)
+        pass_s = ((est_s <= tau0[:, None]) & svalid).sum(-1)
+        ewma0 = (pass_s / jnp.maximum(svalid.sum(-1), 1)).astype(jnp.float32)
+        return tau0, ewma0
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "forced"))
@@ -907,11 +932,13 @@ def _anytime_topk(state: dict, blocks: dict, q_lead, q_tail, q_extra: dict,
         xs_g = {key: v[done:done + g] for key, v in blocks.items()}
         carry = _anytime_group(state, xs_g, q_lead, q_tail, q_extra, probe,
                                carry, cfg)
+        group = done // G
         done += g
         # the sync that makes the wall check honest: without it the python
         # loop races ahead of the async device queue and the deadline only
         # fires after every group has already been dispatched
-        jax.block_until_ready(carry[0])
+        with span("search.group_sync", group=group):
+            jax.block_until_ready(carry[0])
         faults.sleep_block(fp)
         if time.monotonic() > deadline_ts:
             break
@@ -974,7 +1001,19 @@ def stream_topk(state: dict, q_lead, q_tail, cfg: DcoEngineConfig,
     blocks may hold true neighbors); the facade's ``uncertified_mask``
     encodes this.  Anytime mode serves the fixed scan only — the backend
     strips an adaptive policy before a deadline call.
+
+    The call runs inside the ``search.dispatch`` host span, whose counters
+    are the query chunks and, under the adaptive policy, the chunks routed
+    to the full-scan body.
     """
+    with span("search.dispatch") as sp:
+        return _dispatch(sp, state, q_lead, q_tail, cfg, q_extra, probe,
+                         blocks, deadline_ts, block_group)
+
+
+def _dispatch(sp, state, q_lead, q_tail, cfg, q_extra, probe, blocks,
+              deadline_ts, block_group):
+    """The body of :func:`stream_topk`; ``sp`` is its dispatch span."""
     q_extra = dict(q_extra or {})
     adaptive = _adaptive(cfg)
     # adaptive mode forces the jnp dco_scan path (the kernel freezes pruned
@@ -1002,6 +1041,7 @@ def stream_topk(state: dict, q_lead, q_tail, cfg: DcoEngineConfig,
         raise ValueError("stream_topk needs at least one query")
     c = min(cfg.query_chunk, nq)
     pad = (-nq) % c
+    sp.set_metadata(chunks=(nq + pad) // c)
     if pad:
         q_lead = jnp.pad(q_lead, ((0, pad), (0, 0)))
         q_tail = jnp.pad(q_tail, ((0, pad), (0, 0)))
@@ -1051,11 +1091,13 @@ def stream_topk(state: dict, q_lead, q_tail, cfg: DcoEngineConfig,
         thr = pass_threshold(D, d_screen, d_complete,
                              cfg.policy.fallback_margin,
                              cfg.policy.overhead_dims)
-        chunk_full = np.asarray(
-            (ew0 > thr) & q_valid).reshape(nchunks, c).any(1)
+        with span("search.seed_sync"):
+            chunk_full = np.asarray(
+                (ew0 > thr) & q_valid).reshape(nchunks, c).any(1)
     else:
         tau0 = ew0 = None
         chunk_full = np.zeros(nchunks, bool)
+    sp.set_metadata(full_chunks=int(chunk_full.sum()))
     outs = []
     for ci in range(nchunks):
         sl = slice(ci * c, (ci + 1) * c)

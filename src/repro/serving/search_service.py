@@ -58,6 +58,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.core.engine import EXTRA_COVERAGE, EXTRA_UNCERTIFIED_MASK
+from repro.utils.spans import span
 
 #: Terminal ticket states (``SearchRequest.status``); "pending" is the only
 #: non-terminal one.  Exactly one terminal state per submitted request.
@@ -279,18 +280,29 @@ class SearchService:
         Returns every request *resolved* by this step — served ones plus
         any that timed out in the queue ([] when nothing was pending)."""
         t_now = self._clock() if now is None else now
-        resolved = self._expire_queued(t_now)
         if not self._queue:
-            return resolved
-        batch = [self._queue.popleft()
-                 for _ in range(min(self.slots, len(self._queue)))]
-        Q = np.stack([r.q for r in batch])
-        if len(batch) < self.slots:
-            # pad with a replay of the last real query: static (slots, D)
-            # shape -> the jitted graph compiles once for the service
-            Q = np.concatenate(
-                [Q, np.broadcast_to(Q[-1], (self.slots - len(batch),
-                                            Q.shape[1]))])
+            return []
+        with span("search.step", step=self.steps, slots=self.slots) as sp:
+            return self._serve(sp, t_now, now)
+
+    def _serve(self, sp, t_now: float, now: float | None):
+        """The body of ``step`` for a non-empty queue; ``sp`` is its
+        ``search.step`` span."""
+        with span("search.batch") as sb:
+            resolved = self._expire_queued(t_now)
+            sb.set_metadata(expired=len(resolved))
+            if not self._queue:
+                return resolved
+            batch = [self._queue.popleft()
+                     for _ in range(min(self.slots, len(self._queue)))]
+            sp.set_metadata(queries=len(batch), first_rid=batch[0].rid)
+            Q = np.stack([r.q for r in batch])
+            if len(batch) < self.slots:
+                # pad with a replay of the last real query: static (slots, D)
+                # shape -> the jitted graph compiles once for the service
+                Q = np.concatenate(
+                    [Q, np.broadcast_to(Q[-1], (self.slots - len(batch),
+                                                Q.shape[1]))])
         # the batch scans together, so its anytime budget is the tightest
         # member's remaining budget (members with no budget impose none)
         budgets = [r.t_deadline - t_now for r in batch
@@ -315,28 +327,29 @@ class SearchService:
             self.steps += 1
             self.busy_s += wall
             return resolved + batch
-        t_done = (now + wall) if now is not None else self._clock()
-        mask = res.stats.extra.get(EXTRA_UNCERTIFIED_MASK)
-        cov = res.stats.extra.get(EXTRA_COVERAGE)
-        stats = {key: v for key, v in res.stats.extra.items()
-                 if np.isscalar(v)}
-        n_visible = self._visible_rows()
-        for j, req in enumerate(batch):
-            req.ids = res.ids[j]
-            req.dists = res.dists[j]
-            req.certified = None if mask is None else bool(~mask[j])
-            if req.certified is False:
-                self.uncertified += 1
-            req.coverage = None if cov is None else float(cov[j])
-            if req.coverage is not None and req.coverage < 1.0:
-                self.partials += 1
-            req.stats = stats
-            req.status = "done"
-            req.t_done = t_done
-            req.service_s = wall
-            req.batch_size = len(batch)
-            req.n_visible = n_visible
-            self._observe_latency(req)
+        with span("search.tickets", served=len(batch)):
+            t_done = (now + wall) if now is not None else self._clock()
+            mask = res.stats.extra.get(EXTRA_UNCERTIFIED_MASK)
+            cov = res.stats.extra.get(EXTRA_COVERAGE)
+            stats = {key: v for key, v in res.stats.extra.items()
+                     if np.isscalar(v)}
+            n_visible = self._visible_rows()
+            for j, req in enumerate(batch):
+                req.ids = res.ids[j]
+                req.dists = res.dists[j]
+                req.certified = None if mask is None else bool(~mask[j])
+                if req.certified is False:
+                    self.uncertified += 1
+                req.coverage = None if cov is None else float(cov[j])
+                if req.coverage is not None and req.coverage < 1.0:
+                    self.partials += 1
+                req.stats = stats
+                req.status = "done"
+                req.t_done = t_done
+                req.service_s = wall
+                req.batch_size = len(batch)
+                req.n_visible = n_visible
+                self._observe_latency(req)
         self.steps += 1
         self.completed += len(batch)
         self.busy_s += wall

@@ -1,1 +1,2 @@
-from repro.utils.timing import Timer, bench_call  # noqa: F401
+"""Small helpers shared across the package: the compile cache and the
+names of the profiler spans and device scopes."""

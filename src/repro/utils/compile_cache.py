@@ -18,11 +18,18 @@ def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its directory.
 
     ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
-    nothing is set here.  Otherwise the cache goes to :data:`REPO_CACHE_DIR`.
+    no directory is set here.  Otherwise the cache goes to
+    :data:`REPO_CACHE_DIR`.
+
+    The cache key includes the programs' op metadata: by default JAX strips
+    it, so a program that differs from a cached one only in its device
+    scopes (``repro.utils.spans``) would load the cached executable and
+    trace under the old program's op names.
     """
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
     return str(REPO_CACHE_DIR)

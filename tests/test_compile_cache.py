@@ -8,8 +8,10 @@ from repro.utils.compile_cache import REPO_CACHE_DIR, enable_compile_cache
 @pytest.fixture
 def restore_cache_dir():
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
 
 
 @pytest.mark.parametrize("placed", [False, True])
@@ -23,6 +25,9 @@ def test_compile_cache_directory(placed, tmp_path, monkeypatch,
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     before = jax.config.jax_compilation_cache_dir
     got = enable_compile_cache()
+    # op metadata (device scopes) is part of the key: a cached executable
+    # of a program with other scopes is not loaded in its place
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
     if placed:
         assert got == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == before
