@@ -20,7 +20,7 @@ import numpy as np
 from repro.core.engine import (EXTRA_COVERAGE, EXTRA_DIMS_READ_MEAN,
                                EXTRA_EST_SAVED_FLOPS, EXTRA_FALLBACK_BLOCKS,
                                EXTRA_RULE_TIMELINE, EXTRA_SCREEN_PASS_MEAN,
-                               EXTRA_SURVIVORS_MEAN, EXTRA_UNCERTIFIED_MASK,
+                               EXTRA_SHARED_BLOCKS, EXTRA_SURVIVORS_MEAN, EXTRA_UNCERTIFIED_MASK,
                                EXTRA_UNCERTIFIED_QUERIES, QueryBatch,
                                ScanStats, scan_topk)
 from repro.core.policy import PolicyConfig, finalize_adaptive_extra
@@ -592,7 +592,7 @@ class JaxBackend:
                 or cfg.policy is not None or t_end is not None):
             engine = "stream"       # only the streaming engine serves these
         cand_per_q = np.full(nq, N, np.float64)
-        passed = dmin = report = coverage = dims_read = None
+        passed = dmin = report = coverage = dims_read = shared = None
         n_anchor = 0                # two_stage completes k anchors per query
         if self.mesh is None:
             if engine == "two_stage":
@@ -639,11 +639,16 @@ class JaxBackend:
             if engine == "two_stage":
                 d, i, surv = out
             elif cfg.policy is not None:
-                d, i, surv, passed, dmin, dims_read, report = out
+                d, i, surv, passed, dmin, dims_read, shared, report = out
             elif t_end is not None:
-                d, i, surv, passed, dmin, dims_read, coverage = out
+                d, i, surv, passed, dmin, dims_read, shared, coverage = out
             else:
-                d, i, surv, passed, dmin, dims_read = out
+                d, i, surv, passed, dmin, dims_read, shared = out
+            if shared is not None:
+                # blocks each query's chunk completed chunk-shared, over
+                # the blocks it scanned
+                shared = float(shared.mean()) / (
+                    blocks["xl"].shape[0] * (coverage or 1.0))
             if coverage is not None:
                 # partial scans only touched this fraction of the corpus:
                 # charge candidate work pro rata so pruning stats stay honest
@@ -663,7 +668,7 @@ class JaxBackend:
                 jax.block_until_ready(d)
             if engine == "two_stage":
                 n_anchor = nq * k * int(np.prod(tuple(self.mesh.shape.values())))
-        with span("search.finish"):
+        with span("search.finish") as sp:
             stats = ScanStats(n_dco=int(cand_per_q.sum()),
                               dims_total=float((cand_per_q * D).sum()))
             if cfg.kind == "fdscan":
@@ -696,6 +701,9 @@ class JaxBackend:
                     np.asarray(dims_read, np.float64).sum())
             stats.extra[EXTRA_DIMS_READ_MEAN] = (
                 stats.dims_scanned / max(stats.n_dco, 1))
+            if shared is not None:
+                stats.extra[EXTRA_SHARED_BLOCKS] = shared
+                sp.set_metadata(shared_block_share=shared)
             if report is not None:
                 stats.extra[EXTRA_FALLBACK_BLOCKS] = float(
                     np.asarray(report["fallback_blocks"]).mean())

@@ -11,7 +11,7 @@ from repro.core.engine import (EXTRA_AUDIT_RECALL, EXTRA_BREAKER_STATE,
                                EXTRA_EST_SAVED_FLOPS, EXTRA_FALLBACK_BLOCKS,
                                EXTRA_HEDGED, EXTRA_REPLICA,
                                EXTRA_RULE_TIMELINE, EXTRA_SCREEN_PASS_MEAN,
-                               EXTRA_SURVIVORS_MEAN, EXTRA_UNCERTIFIED_MASK,
+                               EXTRA_SHARED_BLOCKS, EXTRA_SURVIVORS_MEAN, EXTRA_UNCERTIFIED_MASK,
                                EXTRA_UNCERTIFIED_QUERIES, ScanStats,
                                make_schedule)
 
@@ -61,6 +61,13 @@ STAT_EXTRA_KEYS: dict = {
         "stream and host paths (per-group/per-stage alive counts, "
         "DESIGN.md §8); formula-derived on the legacy two_stage engine "
         "and the mesh path (screen dims + completed tails).",
+    EXTRA_SHARED_BLOCKS:
+        "Streaming engine, single device: mean over the batch's queries "
+        "of the share of scanned row blocks whose screen survivors the "
+        "query's chunk completed through the chunk-shared path (the "
+        "chunk's survivors spanned at most block_capacity distinct rows, "
+        "so none was dropped; DESIGN.md §4).  Absent on the host path, "
+        "the two_stage engine and the mesh path.",
     EXTRA_DRIFT_SCORE:
         "Guardrail sessions only (SchedulePolicy.guardrails armed): the "
         "drift sentinel's EWMA-smoothed query-drift score for this batch, "

@@ -32,6 +32,8 @@ EXTRA_COVERAGE = "coverage"                      # per-query scanned fraction
                                                  # (anytime search; 1.0 = full)
 EXTRA_DIMS_READ_MEAN = "dims_read_mean"          # dims touched per candidate
                                                  # (screen + completed tails)
+EXTRA_SHARED_BLOCKS = "shared_block_share"       # stream: share of scanned
+                                                 # blocks completed chunk-shared
 EXTRA_DRIFT_SCORE = "drift_score"                # guardrails: EWMA drift score
 EXTRA_AUDIT_RECALL = "audit_recall"              # guardrails: audited recall EWMA
 EXTRA_BREAKER_STATE = "breaker_state"            # guardrails: breaker state that

@@ -277,8 +277,8 @@ def make_distributed_topk(mesh, cfg: DcoEngineConfig, shard_axes=("data", "model
                  "lead_sq": lead_sq, "tail_sq": tail_sq, **extra_state}
         if engine == "stream":
             from repro.core.stream_engine import stream_topk
-            d, i, surv, _, dmin, _ = stream_topk(state, q_lead, q_tail, cfg,
-                                                 q_extra)
+            d, i, surv, _, dmin, _, _ = stream_topk(state, q_lead, q_tail,
+                                                    cfg, q_extra)
         else:
             d, i, surv = two_stage_topk(state, q_lead, q_tail, cfg, q_extra)
             dmin = jnp.full(d.shape[0], jnp.inf)

@@ -9,8 +9,13 @@ walks the rotated corpus in candidate row blocks under ``lax.scan``:
               distances and screens against the *running* tau (its keep-count
               output is the per-block survivor tally, so no (N, Q) array
               ever leaves the loop);
-  compaction  survivors are compacted on device — top-``block_capacity`` by
-              estimate — and tail-completed (trailing D-d1 rotated dims);
+  compaction  survivors are compacted on device and tail-completed
+              (trailing D-d1 rotated dims).  When the query chunk's
+              survivors together span at most ``block_capacity`` distinct
+              rows, those rows are selected once for the whole chunk,
+              gathered once and completed for every query, dropping
+              nothing; otherwise each query keeps its own
+              top-``block_capacity`` by estimate;
   merge       completed rows fold into a carried per-query top-k whose k-th
               distance tightens tau for every later block — the monotone
               pruning a one-shot anchor tau cannot achieve.
@@ -282,6 +287,11 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
         tail_min = state.get("tail_min", state["tail_sq"]).min()
 
     Cp = min(C + 1, B)      # +1 slot observes the best DROPPED estimate
+    q_okm = jnp.ones((c,), bool) if q_ok is None else q_ok
+    # the screened scans walk block indices (``blk["b"]``) and read tail rows
+    # from the layout by index: a scanned (B, Dt) slice would be copied
+    # whole every block to feed a gather of a few rows
+    xt_all = xs["xt"]
 
     # ---- PDX vertical layout (DESIGN.md §8) -------------------------------
     grouped = xs["xl"].ndim == 4
@@ -319,6 +329,75 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
                              pol.fallback_margin, pol.overhead_dims)
 
     def _complete_screened(best_d, best_i, tau, keep, est, partial, blk):
+        """Complete a flat block's screen survivors; returns the completion
+        tuple of :func:`_complete_per_query` plus whether the block took
+        the chunk-shared path.  When the chunk's real queries together keep
+        at most ``C`` distinct rows, one shared selection and one gather of
+        those rows serve every query and nothing is dropped; otherwise each
+        query keeps its own top-``C`` by estimate.  ``opq`` completes over
+        the lead rows too and always takes the per-query path."""
+        if cfg.kind == "opq":
+            return _complete_per_query(best_d, best_i, tau, keep, est,
+                                       partial, blk) + (jnp.asarray(False),)
+        with jax.named_scope("dco.compact"):
+            union = (keep & q_okm[:, None]).any(0)                 # (B,)
+            n_union = union.sum()
+        shared = n_union <= C
+        return jax.lax.cond(
+            shared,
+            lambda: _complete_shared(best_d, best_i, tau, keep, partial,
+                                     blk, union, n_union),
+            lambda: _complete_per_query(best_d, best_i, tau, keep, est,
+                                        partial, blk)) + (shared,)
+
+    def _complete_shared(best_d, best_i, tau, keep, partial, blk, union,
+                         n_union):
+        """Chunk-shared completion: the union's rows, selected once in row
+        order, gathered once and completed for every query of the chunk as
+        ``_complete_full`` completes a whole block.  Every survivor is
+        completed, so nothing is dropped (certificate +inf).  Padding
+        queries of a ragged adaptive chunk see only the union's rows; their
+        answers are discarded."""
+        with jax.named_scope("dco.compact"):
+            # slot j takes the union's (j+1)-th row: the count of rows whose
+            # running union count is <= j.  A compare and a reduce; no sort,
+            # and no scatter, which a TPU runs one update at a time
+            slot = jnp.arange(C, dtype=jnp.int32)
+            seen = _running_count(union)                           # (B,)
+            cand = jnp.minimum(
+                (seen[None, :] <= slot[:, None]).sum(1, dtype=jnp.int32),
+                B - 1)                                             # (C,)
+            live = keep[:, cand] & (slot < n_union)[None, :]
+        with jax.named_scope("dco.tail"):
+            c_tail = xt_all[blk["b"], cand]                        # (C, Dt)
+            tail = jnp.maximum(
+                blk["tsq"][cand][None, :]
+                - 2.0 * jnp.matmul(qt, c_tail.T, precision=HIGHEST)
+                + qt_sq[:, None], 0.0)
+            exact = jnp.where(live, partial[:, cand] + tail, jnp.inf)
+            ids = jnp.broadcast_to(blk["ids"][cand][None, :], (c, C))
+        new_d, new_i, new_tau = _merge_topk(best_d, best_i, tau, exact, ids,
+                                            cfg)
+        return (new_d, new_i, new_tau, live.sum(-1).astype(jnp.int32),
+                jnp.full((c,), jnp.inf, jnp.float32))
+
+    def _running_count(mask):
+        """Inclusive running count of a (B,) bool mask, as two MXU products
+        over 128-row lanes: 0/1 entries and per-lane counts of at most 128
+        are exact in bfloat16, and their float32 sums (at most B) are exact.
+        A cumsum would do, but XLA rewrites it on a TPU into reduce-windows
+        that lose the op's name, so a trace could not give it a stage."""
+        L = 128
+        R = -(-B // L)
+        m = jnp.pad(mask, (0, R * L - B)).reshape(R, L).astype(jnp.bfloat16)
+        upto = jnp.triu(jnp.ones((L, L), jnp.bfloat16))           # i <= j
+        within = jnp.matmul(m, upto, preferred_element_type=jnp.float32)
+        before = jnp.tril(jnp.ones((R, R), jnp.bfloat16), -1)     # r' < r
+        offset = jnp.matmul(before, within[:, -1].astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32)
+        return (within + offset[:, None]).reshape(-1)[:B].astype(jnp.int32)
+
+    def _complete_per_query(best_d, best_i, tau, keep, est, partial, blk):
         # ---- on-device compaction: top-C survivors by estimate ------------
         with jax.named_scope("dco.compact"):
             score = jnp.where(keep, est, jnp.inf)
@@ -336,7 +415,7 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             alive = (neg_s > -jnp.inf) & (col < C)
         with jax.named_scope("dco.tail"):
             rows = jnp.arange(c)[:, None]
-            c_tail = blk["xt"][cand]                          # (c, Cp, Dt)
+            c_tail = xt_all[blk["b"], cand]                   # (c, Cp, Dt)
             tail = jnp.maximum(((c_tail - qt[:, None, :]) ** 2).sum(-1), 0.0)
             if cfg.kind == "opq":
                 c_lead = blk["xl"][cand]
@@ -426,7 +505,7 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             alive = (neg_s > -jnp.inf) & (col < C)
             rsel = jnp.take_along_axis(cand, sel, axis=1)     # (c, CpR)
         with jax.named_scope("dco.tail"):
-            c_tail = blk["xt"][rsel]                          # (c, CpR, Dt)
+            c_tail = xt_all[blk["b"], rsel]                   # (c, CpR, Dt)
             tail = jnp.maximum(((c_tail - qt[:, None, :]) ** 2).sum(-1), 0.0)
             exact = jnp.take_along_axis(acc, sel, axis=1) + tail
             exact = jnp.where(alive, exact, jnp.inf)
@@ -444,18 +523,24 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             with jax.named_scope("dco.lead"):
                 partial = _lead_partial(blk)
         new_d, new_i, new_tau = _merge_topk(
-            best_d, best_i, tau, _complete_full(blk, partial, ok),
+            best_d, best_i, tau,
+            _complete_full(blk, _block_tail(blk), partial, ok),
             jnp.broadcast_to(blk["ids"][None, :], (c, B)), cfg)
         return (new_d, new_i, new_tau, ok.sum(-1).astype(jnp.int32),
                 jnp.full((c,), jnp.inf, jnp.float32))
 
-    def _complete_full(blk, partial, ok):
+    def _block_tail(blk):
+        """The current block's (B, Dt) tail rows, for a full completion."""
+        return jax.lax.dynamic_index_in_dim(xt_all, blk["b"], keepdims=False)
+
+    def _complete_full(blk, xt, partial, ok):
         """Exact distances of every row of the block: the lead partial plus
-        the full-scan tail product; rows outside ``ok`` read +inf."""
+        the full-scan tail product over its tail rows ``xt``; rows outside
+        ``ok`` read +inf."""
         with jax.named_scope("dco.tail"):
             exact = partial + jnp.maximum(
                 blk["tsq"][None, :]
-                - 2.0 * jnp.matmul(qt, blk["xt"].T, precision=HIGHEST)
+                - 2.0 * jnp.matmul(qt, xt.T, precision=HIGHEST)
                 + qt_sq[:, None], 0.0)
             return jnp.where(ok, exact, jnp.inf)
 
@@ -542,7 +627,8 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
                 best_d, best_i, tau, keepR, estR, acc, cand, dropped0, blk)
             dims_b = dims_scr + completed.astype(jnp.float32) * (D - d1)
             return ((new_d, new_i, new_tau, surv + completed,
-                     passed + passed_b, dims + dims_b), dropped)
+                     passed + passed_b, dims + dims_b),
+                    (dropped, jnp.asarray(False)))
 
         with jax.named_scope("dco.lead"):
             partial, est, keep, passed_b, dims_scr = _lead_screen(
@@ -550,20 +636,21 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
 
         if cfg.kind == "fdscan":
             new_d, new_i, _ = _merge_topk(
-                best_d, best_i, tau, _complete_full(blk, partial, okm),
+                best_d, best_i, tau,
+                _complete_full(blk, _block_tail(blk), partial, okm),
                 jnp.broadcast_to(blk["ids"][None, :], (c, B)), cfg)
             n_done = okm.sum(-1).astype(jnp.int32)
             new_tau = jnp.full((c,), jnp.inf)
             return ((new_d, new_i, new_tau, surv + n_done, passed + n_done,
                      dims + n_okq * float(D)),
-                    jnp.full((c,), jnp.inf))
+                    (jnp.full((c,), jnp.inf), jnp.asarray(False)))
 
-        new_d, new_i, new_tau, completed, dropped = _complete_screened(
+        new_d, new_i, new_tau, completed, dropped, shared = _complete_screened(
             best_d, best_i, tau, keep, est, partial, blk)
         comp_w = float(D if cfg.kind == "opq" else D - d1)
         dims_b = dims_scr + completed.astype(jnp.float32) * comp_w
         return ((new_d, new_i, new_tau, surv + completed,
-                 passed + passed_b, dims + dims_b), dropped)
+                 passed + passed_b, dims + dims_b), (dropped, shared))
 
     # ---- adaptive serving (DESIGN.md §5) ----------------------------------
     # One lax.cond per block whose branches are SELF-CONTAINED (each computes
@@ -575,7 +662,6 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
     # recompute-from-scratch SPILL escape (survivors over block_capacity
     # complete the block exactly), so screened blocks never drop rows and
     # adaptive scans are certified by construction.
-    q_okm = jnp.ones((c,), bool) if q_ok is None else q_ok
 
     def _lead_partial(blk):
         xl = blk["xl"]
@@ -647,6 +733,7 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
                 lambda: _complete_all(best_d, best_i, tau, None, ok, blk),
                 lambda: _complete_compacted(best_d, best_i, tau, keepR, estR,
                                             acc, cand, dropped0, blk))
+            shared = jnp.asarray(False)
             dims_b = jnp.where(
                 esc, dims_scr + nokf * float(D),
                 dims_scr + completed.astype(jnp.float32) * float(D - d1))
@@ -662,9 +749,10 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             # compaction; the escape reuses the stage-1 partial, which
             # crosses the boundary anyway as an operand of the screened
             # branch
-            new_d, new_i, new_tau, completed, dropped = jax.lax.cond(
+            new_d, new_i, new_tau, completed, dropped, shared = jax.lax.cond(
                 esc,
-                lambda: _complete_all(best_d, best_i, tau, partial, ok, blk),
+                lambda: _complete_all(best_d, best_i, tau, partial, ok, blk)
+                + (jnp.asarray(False),),
                 lambda: _complete_screened(best_d, best_i, tau, keep, est,
                                            partial, blk))
             dims_b = jnp.where(
@@ -707,24 +795,33 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             "saved": ps["saved"] + 2.0 * saved_blk,
         }
         return ((new_d, new_i, new_tau, surv + completed, passed + passed_b,
-                 dims + dims_b, new_ps), (dropped, esc.astype(jnp.float32)))
+                 dims + dims_b, new_ps),
+                (dropped, shared, esc.astype(jnp.float32)))
 
     init = (jnp.full((c, k), jnp.inf, jnp.float32),
             jnp.full((c, k), -1, jnp.int32),
             jnp.full((c,), jnp.inf, jnp.float32),
             jnp.zeros((c,), jnp.int32), jnp.zeros((c,), jnp.int32),
             jnp.zeros((c,), jnp.float32))
+    nb = xs["xl"].shape[0]
+    # the screened scans' blocks: every per-row array but the tail rows,
+    # and the block's index into the layout
+    xs_b = {key: v for key, v in xs.items() if key != "xt"}
+    xs_b["b"] = jnp.arange(nb, dtype=jnp.int32)
+
+    def n_shared(shared):   # blocks completed by the chunk-shared path
+        return jnp.full((c,), shared.sum(), jnp.int32)
+
     if pol is None:
         if init_carry is not None:
             init = init_carry
         with jax.named_scope("dco.scan"):
-            carry, dropped = jax.lax.scan(step, init, xs)
+            carry, (dropped, shared) = jax.lax.scan(step, init, xs_b)
         if return_carry:
-            return carry, dropped.min(0)
+            return carry, dropped.min(0), n_shared(shared)
         d, i, _, surv, passed, dims = carry
-        return d, i, surv, passed, dropped.min(0), dims
+        return d, i, surv, passed, dropped.min(0), dims, n_shared(shared)
 
-    nb = xs["xl"].shape[0]
     if init_tau is None:
         init_tau = jnp.full((c,), jnp.inf, jnp.float32)
     if init_ewma is None:
@@ -753,7 +850,8 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
             with jax.named_scope("dco.lead"):
                 partial = _lead_partial(blk)
             nd, ni, ntau = _merge_topk(
-                best_d, best_i, tau, _complete_full(blk, partial, ok),
+                best_d, best_i, tau,
+                _complete_full(blk, blk["xt"], partial, ok),
                 jnp.broadcast_to(blk["ids"][None, :], (c, B)), cfg)
             n_ok = ok.sum(-1).astype(jnp.int32)
             return (nd, ni, ntau, surv + n_ok, passed + n_ok,
@@ -766,18 +864,19 @@ def _scan_blocks(cfg: DcoEngineConfig, state, xs, ql, qt, qe, pr, B, D,
                   "saved": jnp.zeros((c,), jnp.float32),
                   "timeline": jnp.ones((nb,), jnp.float32)}
         return (d, i, surv, passed, jnp.full((c,), jnp.inf, jnp.float32),
-                dims, report)
+                dims, jnp.zeros((c,), jnp.int32), report)
 
     ini = init + ({"ewma": init_ewma, "n": init_n,
                    "mode": jnp.asarray(False),
                    "fb": jnp.asarray(0, jnp.int32),
                    "saved": jnp.zeros((c,), jnp.float32)},)
     with jax.named_scope("dco.scan"):
-        (d, i, _, surv, passed, dims, ps), (dropped, modes) = jax.lax.scan(
-            step_adaptive, ini, xs)
+        (d, i, _, surv, passed, dims, ps), (dropped, shared, modes) = (
+            jax.lax.scan(step_adaptive, ini, xs_b))
     report = {"fb": jnp.broadcast_to(ps["fb"], (c,)),
               "saved": ps["saved"], "timeline": modes}
-    return d, i, surv, passed, dropped.min(0), dims, report
+    return (d, i, surv, passed, dropped.min(0), dims, n_shared(shared),
+            report)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -797,11 +896,8 @@ def _stream_topk_padded(state: dict, xs: dict, q_lead, q_tail, q_extra: dict,
         cql, cqt, cqe, cpr = args
         return _scan_blocks(cfg, state, xs, cql, cqt, cqe, cpr, B, D)
 
-    d, i, surv, passed, dmin, dims = jax.lax.map(one_chunk, (ql, qt, qe, pr))
-    k = cfg.k
-    return (d.reshape(nq, k), i.reshape(nq, k),
-            surv.reshape(nq), passed.reshape(nq), dmin.reshape(nq),
-            dims.reshape(nq))
+    out = jax.lax.map(one_chunk, (ql, qt, qe, pr))
+    return tuple(a.reshape(nq, *a.shape[2:]) for a in out)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -811,8 +907,8 @@ def _anytime_group(state: dict, xs: dict, q_lead, q_tail, q_extra: dict,
 
     ``carry`` is the whole padded batch's running state —
     ``(best_d (nq,k), best_i (nq,k), tau (nq,), surv (nq,), passed (nq,),
-    dims (nq,), dropped_min (nq,))`` — threaded between jit calls by the
-    anytime driver
+    dims (nq,), dropped_min (nq,), shared (nq,))`` — threaded between jit
+    calls by the anytime driver
     in :func:`stream_topk` (DESIGN.md §7).  Each call advances every query
     chunk by this group's blocks and returns the updated carry; the group
     boundary is the python-level point where the deadline is checked."""
@@ -829,9 +925,10 @@ def _anytime_group(state: dict, xs: dict, q_lead, q_tail, q_extra: dict,
 
     def one_chunk(args):
         cql, cqt, cqe, cpr, ccar = args
-        new, dmin_g = _scan_blocks(cfg, state, xs, cql, cqt, cqe, cpr, B, D,
-                                   init_carry=ccar[:6], return_carry=True)
-        return new + (jnp.minimum(ccar[6], dmin_g),)
+        new, dmin_g, shared_g = _scan_blocks(
+            cfg, state, xs, cql, cqt, cqe, cpr, B, D, init_carry=ccar[:6],
+            return_carry=True)
+        return new + (jnp.minimum(ccar[6], dmin_g), ccar[7] + shared_g)
 
     out = jax.lax.map(one_chunk, (ql, qt, qe, pr, cc))
     return tuple(a.reshape(nq, *a.shape[2:]) for a in out)
@@ -911,7 +1008,7 @@ def _anytime_topk(state: dict, blocks: dict, q_lead, q_tail, q_extra: dict,
                   block_group: int):
     """Deadline-aware anytime driver (DESIGN.md §7): python loop over block
     groups, one host sync + wall check per group, early exit with the
-    running top-k on expiry.  Returns the 6-tuple of :func:`stream_topk`
+    running top-k on expiry.  Returns the 7-tuple of :func:`stream_topk`
     plus ``coverage`` (fraction of corpus blocks scanned)."""
     from repro.testing import faults
 
@@ -923,7 +1020,8 @@ def _anytime_topk(state: dict, blocks: dict, q_lead, q_tail, q_extra: dict,
              jnp.zeros((nqp,), jnp.int32),
              jnp.zeros((nqp,), jnp.int32),
              jnp.zeros((nqp,), jnp.float32),
-             jnp.full((nqp,), jnp.inf, jnp.float32))
+             jnp.full((nqp,), jnp.inf, jnp.float32),
+             jnp.zeros((nqp,), jnp.int32))
     nb = blocks["xl"].shape[0]
     G = max(1, int(block_group))
     done = 0
@@ -942,9 +1040,9 @@ def _anytime_topk(state: dict, blocks: dict, q_lead, q_tail, q_extra: dict,
         faults.sleep_block(fp)
         if time.monotonic() > deadline_ts:
             break
-    d, i, _, surv, passed, dims, dmin = carry
+    d, i, _, surv, passed, dims, dmin, shared = carry
     return (d[:nq], i[:nq], surv[:nq], passed[:nq], dmin[:nq], dims[:nq],
-            done / nb)
+            shared[:nq], done / nb)
 
 
 def stream_topk(state: dict, q_lead, q_tail, cfg: DcoEngineConfig,
@@ -965,7 +1063,9 @@ def stream_topk(state: dict, q_lead, q_tail, cfg: DcoEngineConfig,
     dropped_min_est (Q,) the smallest estimate among screen survivors any
     capacity cut dropped (+inf when nothing was dropped), dims_read (Q,)
     total dimensions the scan touched for the query — screening reads plus
-    completed tails — the telemetry behind the facade's ``dims_read_mean``).
+    completed tails — the telemetry behind the facade's ``dims_read_mean``,
+    shared_blocks (Q,) row blocks of the query's chunk completed by the
+    chunk-shared path, behind the facade's ``shared_block_share``).
     ``dropped_min_est[q] > dists_sq[q, k-1]`` CERTIFIES exactness for
     lower-bound rules: every dropped row's lower bound exceeds the returned
     k-th distance, so no true neighbor was truncated.  A failed certificate
@@ -977,7 +1077,7 @@ def stream_topk(state: dict, q_lead, q_tail, cfg: DcoEngineConfig,
     inequality covers group-level drops.  fdscan and opq force G=1.
 
     When ``cfg.policy`` is an adaptive ``core.policy.PolicyConfig`` the
-    engine serves blocks adaptively (DESIGN.md §5) and appends a seventh
+    engine serves blocks adaptively (DESIGN.md §5) and appends an eighth
     return value, a report dict with per-query ``fallback_blocks`` /
     ``est_saved_flops`` and a per-block ``rule_timeline`` (fraction of query
     chunks served by fdscan).  Adaptive mode forces ``use_kernel=False`` for
@@ -993,7 +1093,7 @@ def stream_topk(state: dict, q_lead, q_tail, cfg: DcoEngineConfig,
     row blocks, the running carry is synced and the wall clock checked at
     every group boundary, and on expiry the running top-k is returned as a
     partial result.  At least one group is always scanned.  The return
-    gains a seventh element, ``coverage`` — the fraction of corpus blocks
+    gains an eighth element, ``coverage`` — the fraction of corpus blocks
     scanned (1.0 = the full scan, in which case results are bit-identical
     to the non-deadline path: the grouped scan replays the exact same
     per-block step sequence).  Queries with ``coverage < 1`` must be
@@ -1058,9 +1158,9 @@ def _dispatch(sp, state, q_lead, q_tail, cfg, q_extra, probe, blocks,
         return _anytime_topk(state, blocks, q_lead, q_tail, q_extra, probe,
                              cfg, nq, deadline_ts, block_group)
     if not adaptive:
-        d, i, s, p, dm, dr = _stream_topk_padded(state, blocks, q_lead,
-                                                 q_tail, q_extra, probe, cfg)
-        return d[:nq], i[:nq], s[:nq], p[:nq], dm[:nq], dr[:nq]
+        out = _stream_topk_padded(state, blocks, q_lead, q_tail, q_extra,
+                                  probe, cfg)
+        return tuple(a[:nq] for a in out)
 
     # ---- adaptive orchestration (DESIGN.md §5) ----------------------------
     # Per-chunk python dispatch: the seed's pass fraction decides, per query
@@ -1109,14 +1209,13 @@ def _dispatch(sp, state, q_lead, q_tail, cfg, q_extra, probe, blocks,
             None if ew0 is None else ew0[sl],
             cfg, bool(chunk_full[ci])))
     if nchunks == 1:
-        d, i, s, p, dm, dr, rep = outs[0]
+        *res, rep = outs[0]
     else:
-        d, i, s, p, dm, dr = (jnp.concatenate([o[j] for o in outs])
-                              for j in range(6))
-        rep = {key: jnp.concatenate([o[6][key] for o in outs])
+        res = [jnp.concatenate([o[j] for o in outs]) for j in range(7)]
+        rep = {key: jnp.concatenate([o[7][key] for o in outs])
                for key in ("fb", "saved")}
-        rep["timeline"] = jnp.stack([o[6]["timeline"] for o in outs]).mean(0)
+        rep["timeline"] = jnp.stack([o[7]["timeline"] for o in outs]).mean(0)
     report = {"fallback_blocks": rep["fb"][:nq],
               "est_saved_flops": rep["saved"][:nq],
               "rule_timeline": jnp.atleast_1d(rep["timeline"])}
-    return d[:nq], i[:nq], s[:nq], p[:nq], dm[:nq], dr[:nq], report
+    return tuple(a[:nq] for a in res) + (report,)

@@ -27,7 +27,8 @@ SPANS = {
     "search.seed_sync": "host read of the adaptive seed's pass fractions",
     "search.fetch": "copy of the engine's outputs to the host, through "
                     "block_until_ready",
-    "search.finish": "ScanStats, certificate and stats.extra assembly",
+    "search.finish": "ScanStats, certificate and stats.extra assembly "
+                     "(args: shared_block_share on the streaming engine)",
     "search.tickets": "the service's fill loop over the batch (args: served)",
     "search.group_sync": "host sync after one anytime block group "
                          "(args: group)",
@@ -38,7 +39,9 @@ SCOPES = {
     "dco.seed": "the adaptive policy's pre-scan seed (_seed_eval)",
     "dco.lead": "stage-1 lead distances and the screen: the dco_scan kernels, "
                 "the jnp lead product, _lead_partial, the PDX screen",
-    "dco.compact": "survivor top_k and the certificate's observer column",
+    "dco.compact": "the chunk's survivor union and its shared row "
+                   "selection, or the per-query survivor top_k and the "
+                   "certificate's observer column",
     "dco.tail": "tail gather and completion; the full-scan tail product",
     "dco.merge": "the running top-k merge and the tau update",
     "dco.scan": "the scan over row blocks itself: its per-block slices of "

@@ -102,8 +102,8 @@ def test_pdx_matches_row_blocked_engine(n, D, d1, rb, g, k):
     base = _cfg(d1, k=k, row_block=rb, block_capacity=bc)
     pdx = dataclasses.replace(base, dim_groups=g)
     X, Q = _decayed(n, D, seed=n + g)
-    d0, i0, s0, p0, dm0, r0 = _run(X, Q, base)
-    d1_, i1, s1, p1, dm1, r1 = _run(X, Q, pdx)
+    d0, i0, s0, p0, dm0, r0, _ = _run(X, Q, base)
+    d1_, i1, s1, p1, dm1, r1, _ = _run(X, Q, pdx)
     np.testing.assert_array_equal(i0, i1)       # ids bit-identical, always
     if g == 1:                                  # same code path: bitwise
         np.testing.assert_array_equal(d0, d1_)
@@ -144,7 +144,7 @@ def test_pdx_parity_property(n, dim8, gfrac, rbfrac, seed):
     X, Q = _decayed(n, D, nq=3, seed=seed % 10_000)
     base = _cfg(d1, k=k, row_block=rb, block_capacity=min(128, rb))
     d0, i0, *_ = _run(X, Q, base)
-    d1_, i1, s1, p1, dm1, r1 = _run(X, Q, dataclasses.replace(
+    d1_, i1, s1, p1, dm1, r1, _ = _run(X, Q, dataclasses.replace(
         base, dim_groups=g))
     np.testing.assert_array_equal(i0, i1)
     bd, bi = _brute(X, Q, k)
@@ -179,7 +179,7 @@ def test_pdx_rcut_drop_is_flagged_not_silent():
     X, q, nn_id, d1 = _decoy_corpus()
     cfg = _cfg(d1, query_chunk=1, row_block=2048, block_capacity=64,
                dim_groups=4)                # auto R = max(4*64, 512) = 512
-    d, i, s, p, dm, r = _run(X, q, cfg)
+    d, i, s, p, dm, r, _ = _run(X, q, cfg)
     assert nn_id not in i[0]                # the R-cut dropped the true NN...
     assert float(dm[0]) <= float(d[0, -1])  # ...and the certificate says so
 
@@ -188,7 +188,7 @@ def test_pdx_group_capacity_restores_exactness():
     X, q, nn_id, d1 = _decoy_corpus()
     cfg = _cfg(d1, query_chunk=1, row_block=2048, block_capacity=64,
                dim_groups=4, group_capacity=2048)    # R = B: no cut
-    d, i, s, p, dm, r = _run(X, q, cfg)
+    d, i, s, p, dm, r, _ = _run(X, q, cfg)
     assert i[0, 0] == nn_id and float(d[0, 0]) == 4.0
     assert float(dm[0]) > float(d[0, -1])   # certified: nothing low dropped
 
@@ -200,7 +200,7 @@ def test_adaptive_repairs_pdx_rcut_drop():
     X, q, nn_id, d1 = _decoy_corpus()
     cfg = _cfg(d1, query_chunk=1, row_block=2048, block_capacity=64,
                dim_groups=4, policy=PolicyConfig())
-    d, i, s, p, dm, r, rep = _run(X, q, cfg)
+    d, i, s, p, dm, r, _, rep = _run(X, q, cfg)
     assert i[0, 0] == nn_id and float(d[0, 0]) == 4.0
     assert float(dm[0]) > float(d[0, -1])
 
@@ -285,7 +285,7 @@ def test_pdx_kernel_path_matches_jnp():
     X, Q = _decayed(800, 96, seed=29)
     base = _cfg(48, row_block=256, block_capacity=256, dim_groups=4)
     dj, ij, *_ = _run(X, Q, base)
-    dk, ik, sk, pk, dmk, rk = _run(X, Q, dataclasses.replace(
+    dk, ik, sk, pk, dmk, rk, _ = _run(X, Q, dataclasses.replace(
         base, use_kernel=True))
     np.testing.assert_array_equal(ij, ik)
     np.testing.assert_allclose(dj, dk, rtol=1e-5, atol=1e-5)

@@ -135,11 +135,11 @@ def test_adaptive_repairs_capacity_overflow_miss():
     cfg = DcoEngineConfig(kind="lb", d1=d1, k=k, query_chunk=1,
                           row_block=4096, block_capacity=128,
                           use_kernel=False)
-    d0, i0, _, _, dm0, _ = stream_topk(st, ql, qt, cfg)
+    d0, i0, _, _, dm0, _, _ = stream_topk(st, ql, qt, cfg)
     assert 300 not in np.asarray(i0)[0]              # fixed engine: miss...
     assert float(dm0[0]) <= float(d0[0, -1])         # ...flagged, not fixed
     cfga = dataclasses.replace(cfg, policy=PolicyConfig())
-    d1_, i1, s1, p1, dm1, _, rep = stream_topk(st, ql, qt, cfga)
+    d1_, i1, s1, p1, dm1, _, _, rep = stream_topk(st, ql, qt, cfga)
     assert np.asarray(i1)[0, 0] == 300 and float(d1_[0, 0]) == 4.0
     assert not np.isfinite(float(dm1[0]))            # repaired: nothing dropped
     assert float(np.asarray(rep["fallback_blocks"])[0]) > 0

@@ -62,6 +62,18 @@ def test_screened_scan_names_every_stage_op(tiny):
     _assert_scoped(hlo)
     named = {p for o in _stage_ops(hlo) for p in o[2].split("/")}
     assert named >= {"dco.lead", "dco.compact", "dco.tail", "dco.merge"}
+    # the chunk-shared and per-query completions are the two branches of a
+    # per-block cond: each compacts and completes under its own scopes, and
+    # merges under the merge's
+    branches = {}
+    for _, _, name in _stage_ops(hlo):
+        m = re.search(r"/(branch_\d+)_fun/", "/" + name)
+        if m:
+            stage = [p for p in name.split("/") if p in SCOPES][-1]
+            branches.setdefault(m.group(1), set()).add(stage)
+    assert len(branches) == 2, branches
+    for stages in branches.values():
+        assert stages == {"dco.compact", "dco.tail", "dco.merge"}, stages
 
 
 def test_forced_full_scan_body_names_every_stage_op(tiny):

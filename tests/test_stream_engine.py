@@ -1,7 +1,7 @@
 """Streaming engine (core.stream_engine) coverage: parity vs the two-stage
 engine and the host scan across all decision rules, kernel-vs-jnp path
-identity, ragged query batches, ragged corpus blocks, k > capacity, and the
-device-side IVF probe path."""
+identity, ragged query batches, ragged corpus blocks, k > capacity, the
+device-side IVF probe path, and the chunk-shared survivor completion."""
 import dataclasses
 
 import jax.numpy as jnp
@@ -13,7 +13,7 @@ from repro.core.engine import make_schedule
 from repro.core.jax_engine import (DcoEngineConfig, build_device_state,
                                    two_stage_topk)
 from repro.core.methods import make_method
-from repro.core.stream_engine import stream_topk
+from repro.core.stream_engine import build_stream_blocks, stream_topk
 from repro.vecdata.synthetic import recall_at_k
 
 K = 10
@@ -54,7 +54,7 @@ def test_stream_bit_identical_to_two_stage_on_exact_rules(kind, sift_small):
     st = build_device_state(m, cfg.d1)
     Q = jnp.asarray(ds.Q[:8]) @ jnp.asarray(m.state["pca"]["W"])
     d0, i0, _ = two_stage_topk(st, Q[:, :cfg.d1], Q[:, cfg.d1:], cfg)
-    d1_, i1, s1, p1, dm1, _ = stream_topk(st, Q[:, :cfg.d1], Q[:, cfg.d1:], cfg)
+    d1_, i1, s1, p1, dm1, _, _ = stream_topk(st, Q[:, :cfg.d1], Q[:, cfg.d1:], cfg)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
     norms = (ds.X ** 2).sum(1).max() + (ds.Q[:8] ** 2).sum(1).max()
     atol = 8 * np.finfo(np.float32).eps * norms
@@ -108,9 +108,9 @@ def test_stream_kernel_path_matches_jnp_path(sift_small):
             qe = {"lut": jnp.asarray(np.stack([T.pq_query_lut(pq, q)
                                                for q in Q]))}
         ql, qt = jnp.asarray(Q[:, :48]), jnp.asarray(Q[:, 48:])
-        d0, i0, s0, p0, dm0, _ = stream_topk(st, ql, qt, cfg, qe)
+        d0, i0, s0, p0, dm0, _, _ = stream_topk(st, ql, qt, cfg, qe)
         cfgk = dataclasses.replace(cfg, use_kernel=True)
-        d1_, i1, s1, p1, dm1, _ = stream_topk(st, ql, qt, cfgk, qe)
+        d1_, i1, s1, p1, dm1, _, _ = stream_topk(st, ql, qt, cfgk, qe)
         np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1)), name
         np.testing.assert_array_equal(np.asarray(p0), np.asarray(p1)), name
 
@@ -137,7 +137,7 @@ def test_stream_corpus_not_multiple_of_row_block(sift_small):
                               row_block=rb, block_capacity=128,
                               use_kernel=False)
         st = build_device_state(m, cfg.d1)
-        d, i, s, p, dm, _ = stream_topk(st, Q[:, :cfg.d1], Q[:, cfg.d1:], cfg)
+        d, i, s, p, dm, _, _ = stream_topk(st, Q[:, :cfg.d1], Q[:, cfg.d1:], cfg)
         assert (np.asarray(i) >= 0).all() and (np.asarray(i) < ds.n).all()
         assert recall_at_k(np.asarray(i), gt[:8]) == 1.0, rb
 
@@ -153,7 +153,7 @@ def test_stream_k_exceeds_block_capacity(sift_small):
                           row_block=512, block_capacity=16, use_kernel=False)
     st = build_device_state(m, cfg.d1)
     Q = jnp.asarray(ds.Q[:8]) @ jnp.asarray(m.state["pca"]["W"])
-    d, i, s, p, dm, _ = stream_topk(st, Q[:, :cfg.d1], Q[:, cfg.d1:], cfg)
+    d, i, s, p, dm, _, _ = stream_topk(st, Q[:, :cfg.d1], Q[:, cfg.d1:], cfg)
     assert d.shape == (8, k) and np.isfinite(np.asarray(d)).all()
     assert (np.diff(np.asarray(d), axis=1) >= 0).all()      # sorted ascending
     gt, _ = ds.ground_truth(k)
@@ -186,11 +186,11 @@ def test_stream_truncation_is_certified():
     cfg = DcoEngineConfig(kind="lb", d1=d1, k=k, query_chunk=1,
                           row_block=4096, block_capacity=128,
                           use_kernel=False)
-    d, i, s, p, dm, _ = stream_topk(st, ql, qt, cfg)
+    d, i, s, p, dm, _, _ = stream_topk(st, ql, qt, cfg)
     assert 300 not in np.asarray(i)[0]                   # NN was truncated...
     assert float(dm[0]) <= float(d[0, -1])               # ...and flagged
     cfg2 = dataclasses.replace(cfg, block_capacity=512)  # budget > decoys
-    d2, i2, s2, p2, dm2, _ = stream_topk(st, ql, qt, cfg2)
+    d2, i2, s2, p2, dm2, _, _ = stream_topk(st, ql, qt, cfg2)
     assert np.asarray(i2)[0, 0] == 300 and float(d2[0, 0]) == 4.0
     assert float(dm2[0]) > float(d2[0, -1])              # certified exact
 
@@ -237,3 +237,108 @@ def test_stream_survivor_stats_are_real(sift_small):
     assert sm != min(512, ds.n)          # not the old capacity upper bound
     assert res.stats.extra["screen_pass_mean"] >= sm
     assert res.stats.extra["uncertified_queries"] == 0.0
+
+
+# ---- chunk-shared completion (DESIGN.md §4) --------------------------------
+
+#: small blocks, a completion budget of a quarter block and 4-query chunks:
+#: after block 0 a chunk's survivors fit the budget under every rule below
+SHARED = dict(d1=32, query_chunk=4, capacity=512, row_block=512,
+              block_capacity=128)
+
+
+def _steep(n=4096, D=64, nq=16, seed=3):
+    """Rows and queries whose spectrum falls by 0.85 per dimension, in a
+    random basis: the lead dims carry most of every distance, so each
+    rule's screen leaves a few survivors per block and its estimates rank
+    as the exact distances do."""
+    rng = np.random.default_rng(seed)
+    s = 0.85 ** np.arange(D)
+    R = np.linalg.qr(rng.standard_normal((D, D)))[0]
+    X = ((rng.standard_normal((n, D)) * s) @ R).astype(np.float32)
+    Q = ((rng.standard_normal((nq, D)) * s) @ R).astype(np.float32)
+    return X, Q
+
+
+@pytest.mark.parametrize("kind", ["lb", "adsampling", "dade", "ddcres",
+                                  "ratio"])
+def test_shared_completion_is_exact(kind):
+    """Blocks served by the chunk-shared completion return the float64
+    brute force's ids, their distances to f32 rounding, and a passing
+    certificate; block 0 (tau = inf keeps every row) never counts as
+    shared, so the share stays below (nb - 1) / nb."""
+    X, Q = _steep()
+    name = next(m for m, k in RULES.items() if k == kind)
+    res = open_index(X, index="flat", method=name, backend="jax",
+                     schedule=SchedulePolicy(**SHARED)).search(Q, K)
+    d2 = ((Q[:, None].astype(np.float64) - X[None]) ** 2).sum(-1)
+    np.testing.assert_array_equal(res.ids, np.argsort(d2, 1)[:, :K])
+    norms = (X ** 2).sum(1).max() + (Q ** 2).sum(1).max()
+    np.testing.assert_allclose(res.dists, np.take_along_axis(d2, res.ids, 1),
+                               rtol=0,
+                               atol=8 * np.finfo(np.float32).eps * norms)
+    nb = X.shape[0] // SHARED["row_block"]
+    assert 0 < res.stats.extra["shared_block_share"] <= (nb - 1) / nb
+    assert res.stats.extra["uncertified_queries"] == 0.0
+
+
+def test_shared_completion_yields_to_per_query_on_overflow():
+    """The decoys of test_stream_truncation_is_certified, now in block 2 of
+    four: blocks 1 and 3 keep a few rows and take the shared path, the
+    decoy block's union overflows a budget of 128 and takes the per-query
+    path, which truncates the true neighbour and flags it.  A budget of
+    512 holds the decoy block's union: it is shared, nothing is dropped,
+    and the certificate clears.  The neighbour is the block's last row,
+    where the shared path's unused slots point."""
+    rng = np.random.default_rng(0)
+    n, D, d1, k = 4096, 128, 48, 10
+    X = np.zeros((n, D), np.float32)
+    X[:, :d1] = rng.standard_normal((n, d1)).astype(np.float32) * 4.0
+    q = np.zeros(D, np.float32)
+    dec = slice(2048, 2348)     # lead distance ~0.75, tail distance 100
+    X[dec, :d1] = rng.standard_normal((300, d1)).astype(np.float32) / 8.0
+    X[dec, d1] = 10.0
+    nn = 3071                   # the true nearest neighbour: distance 4
+    X[nn] = 0.0
+    X[nn, 0] = 2.0
+    st = {"x_lead": jnp.asarray(X[:, :d1]), "x_tail": jnp.asarray(X[:, d1:]),
+          "lead_sq": jnp.asarray((X[:, :d1] ** 2).sum(1)),
+          "tail_sq": jnp.asarray((X[:, d1:] ** 2).sum(1))}
+    ql, qt = jnp.asarray(q[None, :d1]), jnp.asarray(q[None, d1:])
+    cfg = DcoEngineConfig(kind="lb", d1=d1, k=k, query_chunk=1,
+                          row_block=1024, block_capacity=128,
+                          use_kernel=False)
+    d, i, s, p, dm, _, shared = stream_topk(st, ql, qt, cfg)
+    assert int(shared[0]) == 2                           # blocks 1 and 3
+    assert nn not in np.asarray(i)[0]                    # NN truncated...
+    assert float(dm[0]) <= float(d[0, -1])               # ...and flagged
+    cfg2 = dataclasses.replace(cfg, block_capacity=512)
+    d2, i2, s2, p2, dm2, _, shared2 = stream_topk(st, ql, qt, cfg2)
+    assert int(shared2[0]) == 3                          # blocks 1, 2 and 3
+    assert np.asarray(i2)[0, 0] == nn and float(d2[0, 0]) == 4.0
+    assert len(set(np.asarray(i2)[0].tolist())) == k      # no row twice
+    assert float(dm2[0]) > float(d2[0, -1])              # certified exact
+
+
+def test_block_zero_is_never_shared():
+    """With row_block > block_capacity the first block (tau = inf, every
+    row kept) takes the per-query path: a scan of block 0 alone counts no
+    shared block, the whole scan counts some, and the anytime driver's
+    block groups replay the same count."""
+    X, Q = _steep()
+    m = make_method("PDScanning+").fit(X)
+    cfg = DcoEngineConfig(kind="lb", k=K, use_kernel=False, **SHARED)
+    st = build_device_state(m, cfg.d1)
+    Qr = jnp.asarray(Q) @ jnp.asarray(m.state["pca"]["W"])
+    ql, qt = Qr[:, :cfg.d1], Qr[:, cfg.d1:]
+    blocks = build_stream_blocks(st, cfg.row_block)
+    first = {key: v[:1] for key, v in blocks.items()}
+    assert not np.asarray(stream_topk(st, ql, qt, cfg, blocks=first)[6]).any()
+    full = stream_topk(st, ql, qt, cfg, blocks=blocks)
+    grouped = stream_topk(st, ql, qt, cfg, blocks=blocks, deadline_ts=np.inf,
+                          block_group=3)
+    nb = blocks["xl"].shape[0]
+    assert (0 < np.asarray(full[6])).all() and (np.asarray(full[6]) < nb).all()
+    for a, b in zip(full, grouped[:7]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert grouped[7] == 1.0

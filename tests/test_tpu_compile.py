@@ -3,9 +3,10 @@
 No chip is needed: the TPU compiler compiles for a topology that is
 described and not attached, and refuses what Mosaic would refuse on the
 chip (unaligned block shapes, vector reads at dynamic indices, oversized
-VMEM).  Each test compiles one kernel at the tiles the streaming engine
-selects on a TPU (``core.stream_engine._scan_blocks``) and checks that the
-kernel is in the program as a ``tpu_custom_call``.
+VMEM).  Each kernel test compiles one kernel at the tiles the streaming
+engine selects on a TPU (``core.stream_engine._scan_blocks``) and checks
+that the kernel is in the program as a ``tpu_custom_call``; one more
+compiles the whole screened scan around it.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -97,3 +98,32 @@ def test_pq_lookup_compiles_for_v5e(one_chip, no_compile_cache):
     compiled = jax.jit(
         lambda c, t: pq_lookup(c, t, **KB_PQ)).lower(codes, lut).compile()
     _assert_kernel(compiled)
+
+
+def test_screened_scan_compiles_for_v5e(one_chip, no_compile_cache,
+                                        monkeypatch):
+    """The screened scan as the chip runs it: the dco_scan kernel, then a
+    per-block cond between the chunk-shared and the per-query completion,
+    over two row blocks of a small corpus.  The engine asks the backend
+    whether it is on a TPU; here the test answers for it."""
+    from repro.core import stream_engine as se
+    from repro.core.jax_engine import DcoEngineConfig
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    nb, dt, nq = 2, 128, 2 * QUERY_CHUNK
+    f32 = jnp.float32
+    xs = {"xl": _spec((nb, ROW_BLOCK, D1), f32, one_chip),
+          "xt": _spec((nb, ROW_BLOCK, dt), f32, one_chip),
+          "lsq": _spec((nb, ROW_BLOCK), f32, one_chip),
+          "tsq": _spec((nb, ROW_BLOCK), f32, one_chip),
+          "ids": _spec((nb, ROW_BLOCK), jnp.int32, one_chip)}
+    state = {"tail_sq": _spec((nb * ROW_BLOCK,), f32, one_chip)}
+    cfg = DcoEngineConfig(kind="lb", d1=D1, k=10, query_chunk=QUERY_CHUNK,
+                          row_block=ROW_BLOCK, block_capacity=128,
+                          use_kernel=True)
+    compiled = se._stream_topk_padded.lower(
+        state, xs, _spec((nq, D1), f32, one_chip),
+        _spec((nq, dt), f32, one_chip), {}, None, cfg).compile()
+    _assert_kernel(compiled)
+    assert "conditional(" in compiled.as_text()
