@@ -37,13 +37,19 @@ class CompileClock:
         jax.monitoring.unregister_event_duration_listener(self._on_event)
 
 
-def open_service(cfg: dict, traffic: dict, X: np.ndarray, seed: int):
+def open_service(cfg: dict, traffic: dict, X: np.ndarray, seed: int,
+                 devs: list):
     """The program's own served index over ``X``, as the configuration
-    states it: ``open_index(..., backend="jax", serving=True)``."""
+    states it: ``open_index(..., backend="jax", serving=True)``.  On more
+    than one chip the corpus rows are divided over ``devs``, the cell's
+    chips (``open_index(mesh=)``, a one-axis ``"data"`` mesh)."""
+    from jax.sharding import Mesh
+
     from repro.api import SchedulePolicy, open_index
+    mesh = Mesh(np.asarray(devs), ("data",)) if len(devs) > 1 else None
     return open_index(X, index=cfg["index"], method=cfg["method"],
                       backend="jax", schedule=SchedulePolicy(**cfg["policy"]),
-                      seed=seed, serving=True,
+                      seed=seed, serving=True, mesh=mesh,
                       serving_params={"slots": int(traffic["slots"]),
                                       "k": int(cfg["k"])})
 

@@ -86,8 +86,9 @@ def reader(root, metric: str):
 def problems(root, bench: dict | None = None) -> list:
     """What in ``BENCHMARK.json`` breaks the harness's rules: names and
     units out of their characters, duplicate names, files that cannot be
-    found by name, cells over the four-chip share, metrics that move an
-    end-to-end metric the benchmark does not have or that name no cell."""
+    found by name, cells over the four-chip share, a cell whose corpus rows
+    do not divide evenly over its chips, metrics that move an end-to-end
+    metric the benchmark does not have or that name no cell."""
     root = Path(root)
     bench = load(root) if bench is None else bench
     out = []
@@ -115,12 +116,19 @@ def problems(root, bench: dict | None = None) -> list:
             out.append(f"metric {m['name']}: better {m['better']!r}")
     configs = {c["name"]: c for c in bench["configs"]}
     cells = {w["name"] for w in bench["workloads"]}
+    rows = {}
     for c in bench["configs"]:
         if not (root / c["file"]).is_file():
             out.append(f"config {c['name']}: no file {c['file']}")
+            continue
+        rows[c["name"]] = int(json.loads((root / c["file"]).read_text())
+                              ["data"]["n"])
     for w in bench["workloads"]:
         if w["config"] not in configs:
             out.append(f"workload {w['name']}: no config {w['config']!r}")
+        elif w["config"] in rows and rows[w["config"]] % w["chips"]:
+            out.append(f"workload {w['name']}: n {rows[w['config']]} is not "
+                       f"a multiple of its {w['chips']} chips")
         if not (root / TRAFFIC_DIR / f"{w['traffic']}.json").is_file():
             out.append(f"workload {w['name']}: no traffic file for "
                        f"{w['traffic']!r}")

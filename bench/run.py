@@ -6,11 +6,12 @@ Set-up (``setup_s``, from the start of this script to the first timed
 request): the configuration's corpus and query pools are drawn on the device
 from ``--seed`` and copied to the host, the program builds its own served
 index over them (``open_index(..., backend="jax", serving=True)``, method
-fit included), and every batch shape the window uses is served once per
-query kind.  The window then offers the traffic mix's load for
-``--seconds``.  With ``--trace 1`` a short traced stretch of the same load
-follows the window, and the per-layer metrics are reported instead of the
-end-to-end ones.
+fit included; on a cell of four chips the corpus rows are divided over
+them), and every batch shape the window uses is served once per query
+kind.  The window then offers the traffic mix's load for ``--seconds``.
+With ``--trace 1`` a short traced stretch of the same load follows the
+window, and the per-layer metrics are reported instead of the end-to-end
+ones.
 
 After the window the program's state is freed and a sample of the window's
 answers, drawn from the seed, is compared with a brute force of its own
@@ -45,6 +46,12 @@ import numpy as np  # noqa: E402
 CHECK_SAMPLE = 1024       # window answers compared with the reference
 TRACE_LEAD_S = 0.5        # load before the traced stretch is measured
 TRACE_SECONDS = 1.5       # length of the traced stretch
+# Both are for one chip; a cell of n chips traces 1/n of them.  The
+# profiler's stop_trace, the trace's reading and the readers each take
+# time in step with the device ops traced: on a v5e host, 2.7M ops of one
+# chip (2 s of the 1M x 768 cell) took 84, 9 and 17 s, 10M ops of four
+# chips 184, 34 and 65 s, so a four-chip trace as long as one chip's
+# would not end inside a run's time limit.
 
 
 class NoChip(RuntimeError):
@@ -60,7 +67,8 @@ class Context:
     """What a per-layer metric's reader gets."""
 
     window: object        # bench.window.Window
-    trace: object         # bench.tracing.Trace, or None without --trace 1
+    trace: object         # bench.stages.ProgramTrace, or None without
+                          # --trace 1
     config: dict
     traffic: dict
     peaks: dict | None
@@ -129,23 +137,33 @@ def end_to_end(window, setup_s: float) -> dict:
     return out
 
 
-def traced_stretch(svc, pool, traffic: dict, rng):
+def traced_stretch(svc, pool, traffic: dict, rng, chips: int = 1):
     """The cell's load for a short stretch under the profiler: a lead-in,
-    then ``TRACE_SECONDS`` inside a ``bench.window`` span."""
+    then ``TRACE_SECONDS / chips`` inside a ``bench.window`` span; the
+    trace as ``bench.stages.load`` reads it, with the program's spans and
+    scopes."""
     import jax
-    from bench import driver, tracing
+    from bench import driver, stages, tracing
     tmp = tempfile.mkdtemp(prefix="bench-trace-")
     try:
+        t0 = time.perf_counter()
         jax.profiler.start_trace(tmp)
         try:
-            driver.run_window(svc, pool, traffic, TRACE_LEAD_S, rng,
+            driver.run_window(svc, pool, traffic, TRACE_LEAD_S / chips, rng,
                               trace=True)
             with tracing.span("bench.window", True):
-                driver.run_window(svc, pool, traffic, TRACE_SECONDS, rng,
-                                  trace=True)
+                driver.run_window(svc, pool, traffic, TRACE_SECONDS / chips,
+                                  rng, trace=True)
+            t1 = time.perf_counter()
         finally:
             jax.profiler.stop_trace()
-        return tracing.load(tmp)
+        t2 = time.perf_counter()
+        tr = stages.load(tmp)
+        fact("traced stretch s, stop_trace s, reading its trace s",
+             f"{t1 - t0} {t2 - t1} {time.perf_counter() - t2} "
+             f"({sum(map(len, tr.ops.values()))} device ops on "
+             f"{len(tracing.used_chips(tr))} chips)")
+        return tr
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
@@ -181,7 +199,8 @@ def prepare(root, workload: str, seed: int, *, require_tpu: bool = True,
         gc.collect()
         fact("data generation s", time.perf_counter() - t0)
         t0 = time.perf_counter()
-        svc = driver.open_service(cell.config, cell.traffic, data.X, seed)
+        svc = driver.open_service(cell.config, cell.traffic, data.X, seed,
+                                  devs)
         fact("open_index s", time.perf_counter() - t0)
         t0 = time.perf_counter()
         driver.warm_up(svc, data.pools)
@@ -220,10 +239,10 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool,
                  f"{np.percentile(late, 99) * 1e3} {late.max() * 1e3}")
         fact("window", f"{len(window.requests)} requests, "
              f"{len(window.steps)} steps")
-        tr = (traced_stretch(p.svc, p.pool, cell.traffic, rng) if trace
-              else None)
-        stats = dev.memory_stats() or {}
-        peak = int(stats.get("peak_bytes_in_use", 0))
+        tr = (traced_stretch(p.svc, p.pool, cell.traffic, rng, len(p.devs))
+              if trace else None)
+        mem = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in p.devs]
     finally:
         p.clock.close()
     p.svc = None
@@ -233,17 +252,20 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool,
                                       control=control)
     fact("answers compared", f"{n_checked} in {time.perf_counter() - t0} s")
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(p.devs), "memory_peak_bytes": peak}
+              "count": len(p.devs), "memory_peak_bytes": max(mem),
+              "memory_peak_bytes_per_chip": mem}
     out = {"correct": all(c["value"] <= c["limit"] for c in checks.values()),
            "attempted": len(window.requests), "failed": window.failed()}
     if trace:
         peaks = work.peaks(dev.device_kind) if require_tpu else None
         ctx = Context(window, tr, cell.config, cell.traffic, peaks)
         metrics = {}
+        t0 = time.perf_counter()
         for m in cell.per_layer:
             v = manifest.reader(root, m["name"])(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+        fact("per-layer readers s", time.perf_counter() - t0)
         device["busy_s"] = tracing.device_busy_s(tr)
         device["window_s"] = tracing.window_s(tr)
         out["metrics"] = metrics

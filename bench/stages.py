@@ -1,17 +1,18 @@
 """The program's own spans and device scopes in a profiler trace, and the
 per-stage reductions that read them.
 
-``bench.tracing`` keeps the harness's spans (``bench.*``) and the device
-operations by name.  The program records host spans of its own
-(``search.*``) and names each stage of the streaming engine with a device
-scope (``dco.*``), which lands in the ``op_name`` metadata of the operations
-the stage traced into (``repro.utils.spans`` lists both).  ``load`` reads a
-trace as ``tracing.load`` does and keeps both besides: the program's spans
-beside the harness's in ``spans``, and each device operation's ``op_name``
-in ``scopes``, parallel to ``ops``.  The op tuples stay ``(name, t0, t1)``,
-so every reduction of ``bench.tracing`` reads a ``ProgramTrace`` as it
-reads a ``Trace``; ``idle_gaps`` then names a gap by the innermost program
-span that holds it.
+The harness records its own spans (``bench.*``).  The program records host
+spans of its own (``search.*``) and names each stage of the streaming
+engine with a device scope (``dco.*``), which lands in the ``op_name``
+metadata of the operations the stage traced into (``repro.utils.spans``
+lists both).  ``load`` reads a trace into the device operations of each
+chip by name, both kinds of span in ``spans``, and each device operation's
+``op_name`` in ``scopes``, parallel to ``ops``.  The op tuples are
+``(name, t0, t1)``, so every reduction of ``bench.tracing`` reads a
+``ProgramTrace`` as it reads a ``Trace``; ``idle_gaps`` then names a gap by
+the innermost program span that holds it.  Like those reductions, each
+here is defined over the chips the cell used (``tracing.used_chips``): a
+time is the mean over them of each chip's own.
 
 On a TPU the op's ``op_name`` is the ``tf_op`` stat of the op event's
 metadata, which ``jax.profiler.ProfileData`` does not expose; the few
@@ -31,6 +32,7 @@ the trace around one step boundary, for the tests).
 from __future__ import annotations
 
 import bisect
+import functools
 import sys
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -53,6 +55,8 @@ class ProgramTrace(tracing.Trace):
     ``op_name`` of each device operation ("" where the trace has none)."""
 
     scopes: dict = field(default_factory=dict)  # device -> [op_name]
+    #: ``scope_own_ms`` of this trace, once ``scope_ms`` has read it
+    own_ms: dict | None = field(default=None, compare=False, repr=False)
 
 
 # -- protobuf wire format ----------------------------------------------------
@@ -136,17 +140,43 @@ def op_names(xspace) -> dict:
             events[_first(meta, 1, 0)] = (ev_name, op.removesuffix(":"))
         for g, line in fields:
             if g == 3 and _text(_first(line, 2, b"")) == tracing.OPS_LINE:
-                out[name] = [events.get(_first(e, 1, 0), ("", ""))
-                             for h, e in _fields(line) if h == 4]
+                out[name] = [events.get(m, ("", ""))
+                             for m in _event_ids(line)]
+    return out
+
+
+def _event_ids(line) -> list:
+    """The metadata id of each event (field 4) of one serialized XLine, in
+    order.  An XEvent writes its id (field 1) first, so only its first
+    varint is read; the generic walk is kept for an event that does not."""
+    out = []
+    i, n = 0, len(line)
+    while i < n:
+        key, i = _varint(line, i)
+        wire = key & 7
+        if wire == 0:
+            i = _varint(line, i)[1]
+        elif wire == 2:
+            size, i = _varint(line, i)
+            if key >> 3 == 4:
+                out.append(_varint(line, i + 1)[0]
+                           if size and line[i] == 0x08
+                           else _first(line[i:i + size], 1, 0))
+            i += size
+        elif wire in (1, 5):
+            i += 8 if wire == 1 else 4
+        else:
+            raise ValueError(f"protobuf wire type {wire} is not supported")
     return out
 
 
 # -- reading a trace -----------------------------------------------------------
 
 def load(trace_dir) -> ProgramTrace:
-    """Read the one ``.xplane.pb`` under ``trace_dir``: device operations
-    and spans as ``tracing.load`` reads them, the program's spans too, and
-    each operation's ``op_name``."""
+    """Read the one ``.xplane.pb`` under ``trace_dir``: each TPU plane's
+    "XLA Ops" events, op names cut from their HLO text
+    (``tracing.op_name``), with each one's ``op_name``, and the host's
+    ``bench.*`` and ``search.*`` spans with their arguments."""
     from jax.profiler import ProfileData
 
     files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
@@ -178,6 +208,7 @@ def load(trace_dir) -> ProgramTrace:
     return tr
 
 
+@functools.cache          # a trace repeats a few thousand op_names
 def scope_of(op_name: str):
     """The innermost ``dco.*`` scope of an ``op_name`` path, or None."""
     parts = [p for p in op_name.split("/") if p.startswith(SCOPE_PREFIX)]
@@ -186,35 +217,43 @@ def scope_of(op_name: str):
 
 # -- reductions ----------------------------------------------------------------
 
-def _first_device(tr):
-    dev = next(iter(tr.ops), None)
-    scopes = getattr(tr, "scopes", {})      # a plain Trace has none
-    return (tr.ops[dev], scopes.get(dev, [])) if dev else ([], [])
+def _chips(tr) -> list:
+    """[(ops, op_names)] of the chips the cell used (``tracing.used_chips``);
+    a plain ``Trace`` has no op_names."""
+    scopes = getattr(tr, "scopes", {})
+    return [(ev, scopes.get(dev, []))
+            for dev, ev in tracing.used_chips(tr).items()]
 
 
 def scope_own_ms(tr: ProgramTrace) -> dict:
     """{scope (None: outside every ``dco.*`` scope): mean own ms per whole
-    ``bench.step`` span}: each operation's time without the operations
-    nested in it, as ``tracing.self_times`` counts it, so a ``while`` or a
-    ``conditional`` is not counted twice.  Empty without scoped ops or
-    whole steps."""
-    ops, names = _first_device(tr)
+    ``bench.step`` span and chip}: each operation's time without the
+    operations nested in it, as ``tracing.self_times`` counts it, so a
+    ``while`` or a ``conditional`` is not counted twice.  Empty without
+    scoped ops or whole steps."""
+    chips = _chips(tr)
     steps = tr.step_spans()
-    if not steps or not any(n and scope_of(n) for n in names):
+    if not steps or not any(n and scope_of(n)
+                            for _, names in chips for n in names):
         return {}
-    labelled = [(scope_of(n), a, b) for (_, a, b), n in zip(ops, names)]
     tot: dict = {}
-    for s in steps:
-        for scope, ns in tracing.self_times(labelled, s[1], s[2]).items():
-            tot[scope] = tot.get(scope, 0) + ns
-    return {scope: ns / 1e6 / len(steps) for scope, ns in tot.items()}
+    for ops, names in chips:
+        labelled = [(scope_of(n), a, b) for (_, a, b), n in zip(ops, names)]
+        tot.update((scope, tot.get(scope, 0)) for scope, _, _ in labelled)
+        for s, ev in zip(steps, tracing.per_span(labelled, steps)):
+            for scope, ns in tracing.self_times(ev, s[1], s[2]).items():
+                tot[scope] += ns
+    return {scope: ns / len(chips) / 1e6 / len(steps)
+            for scope, ns in tot.items()}
 
 
 def scope_ms(tr: ProgramTrace, scope: str):
     """Mean own time, ms, of the operations under ``scope`` per whole
-    ``bench.step`` span; None where no operation of the trace carries it."""
-    own = scope_own_ms(tr)
-    return own.get(scope) if scope in own else None
+    ``bench.step`` span; None where no operation of the trace carries it.
+    The reduction runs once per trace, for all the stage readers."""
+    if getattr(tr, "own_ms", None) is None:     # a plain Trace has none
+        tr.own_ms = scope_own_ms(tr)
+    return tr.own_ms.get(scope)
 
 
 def _in_steps(tr, name: str):
@@ -240,16 +279,19 @@ def span_ms(tr, name: str):
 
 def idle_in(tr, name: str):
     """Mean device-idle time, ms, inside the spans named ``name`` per whole
-    ``bench.step`` span (first device); None where the trace has no such
-    span."""
+    ``bench.step`` span and chip; None where the trace has no such span or
+    no chip ran anything."""
     per = _in_steps(tr, name)
-    if per is None:
+    chips = tracing.used_chips(tr)
+    if per is None or not chips:
         return None
-    busy = tracing.union(_first_device(tr)[0])
-    starts = [s for s, _ in busy]
-    idle = sum(b - a - _overlap(busy, starts, a, b)
-               for _, spans in per for _, a, b, _ in spans)
-    return idle / 1e6 / len(per)
+    idle = 0
+    for ops in chips.values():
+        busy = tracing.union(ops)
+        starts = [s for s, _ in busy]
+        idle += sum(b - a - _overlap(busy, starts, a, b)
+                    for _, spans in per for _, a, b, _ in spans)
+    return idle / len(chips) / 1e6 / len(per)
 
 
 def _overlap(busy, starts, a: int, b: int) -> int:
@@ -266,19 +308,23 @@ def _overlap(busy, starts, a: int, b: int) -> int:
 
 def idle_coverage(tr, parent: str = "search.step"):
     """(idle ns inside ``parent`` spans, of it the ns inside one of the
-    parent's child spans), first device, whole spans in the window."""
-    ops, _ = _first_device(tr)
+    parent's child spans), whole spans in the window, mean over the chips
+    the cell used."""
+    chips = tracing.used_chips(tr)
     w0, w1 = tr.window()
-    busy = tracing.union(ops)
+    parents = [(p, tracing.union([s[:3] for s in tr.spans if s is not p
+                                  and s[1] >= p[1] and s[2] <= p[2]]))
+               for p in tr.spans
+               if p[0] == parent and p[1] >= w0 and p[2] <= w1]
     total = covered = 0
-    for p in (s for s in tr.spans
-              if s[0] == parent and s[1] >= w0 and s[2] <= w1):
-        kids = tracing.union([s[:3] for s in tr.spans if s is not p
-                              and s[1] >= p[1] and s[2] <= p[2]])
-        idle = _minus([[p[1], p[2]]], busy)
-        total += sum(b - a for a, b in idle)
-        covered += sum(b - a for a, b in _clip(idle, kids))
-    return total, covered
+    for ops in chips.values():
+        busy = tracing.union(ops)
+        for p, kids in parents:
+            idle = _minus([[p[1], p[2]]], busy)
+            total += sum(b - a for a, b in idle)
+            covered += sum(b - a for a, b in _clip(idle, kids))
+    n = max(len(chips), 1)
+    return total / n, covered / n
 
 
 def _clip(spans, keep) -> list:
@@ -321,45 +367,23 @@ STAGES = ("dco.seed", "dco.lead", "dco.compact", "dco.tail", "dco.merge")
 EXCERPT_PAD_NS = 300_000
 
 
-def traced(svc, pool, traffic: dict, rng) -> ProgramTrace:
-    """``run.traced_stretch``, read by ``load``."""
-    import shutil
-    import tempfile
-
-    import jax
-    from bench import driver
-    from bench.run import TRACE_LEAD_S, TRACE_SECONDS
-    tmp = tempfile.mkdtemp(prefix="bench-trace-")
-    try:
-        jax.profiler.start_trace(tmp)
-        try:
-            driver.run_window(svc, pool, traffic, TRACE_LEAD_S, rng,
-                              trace=True)
-            with tracing.span("bench.window", True):
-                driver.run_window(svc, pool, traffic, TRACE_SECONDS, rng,
-                                  trace=True)
-        finally:
-            jax.profiler.stop_trace()
-        return load(tmp)
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-
-
 def breakdown(tr: ProgramTrace, ctx, root) -> dict:
     """What one traced stretch says per stage and per span."""
     from bench import manifest
     own = scope_own_ms(tr)
     device = manifest.reader(root, "engine.device_ms.bulk")(ctx)
-    ops, names = _first_device(tr)
+    chips = _chips(tr)
     rest: dict = {}
     steps = tr.step_spans()
-    labelled = [((scope_of(n), o[0]), o[1], o[2])
-                for o, n in zip(ops, names)]
-    for s in steps:
-        for key, ns in tracing.self_times(labelled, s[1], s[2]).items():
-            if key[0] not in STAGES:
-                key = f"{key[0]}:{key[1]}"
-                rest[key] = rest.get(key, 0) + ns / 1e6 / len(steps)
+    for ops, names in chips:
+        labelled = [((scope_of(n), o[0]), o[1], o[2])
+                    for o, n in zip(ops, names)]
+        for s, ev in zip(steps, tracing.per_span(labelled, steps)):
+            for key, ns in tracing.self_times(ev, s[1], s[2]).items():
+                if key[0] not in STAGES:
+                    key = f"{key[0]}:{key[1]}"
+                    rest[key] = rest.get(key, 0) + ns
+    per_ms = 1e6 * len(steps) * len(chips)
     idle, covered = idle_coverage(tr) if tr.spans else (0, 0)
     span_names = sorted({s[0] for s in tr.spans
                          if s[0].startswith("search.")})
@@ -369,8 +393,8 @@ def breakdown(tr: ProgramTrace, ctx, root) -> dict:
         "scope_own_ms": {str(k): v for k, v in own.items()},
         "stages_share_of_device": (sum(own.get(s, 0.0) for s in STAGES)
                                    / device if device and own else None),
-        "rest_by_op_ms": sorted(([k, v] for k, v in rest.items() if v > 0),
-                                key=lambda kv: -kv[1])[:12],
+        "rest_by_op_ms": sorted(([k, v / per_ms] for k, v in rest.items()
+                                 if v > 0), key=lambda kv: -kv[1])[:12],
         "span_ms": {n: span_ms(tr, n) for n in span_names},
         "idle_in_ms": {n: idle_in(tr, n) for n in span_names},
         "search_step_idle_ms": idle / 1e6,
@@ -383,11 +407,12 @@ def breakdown(tr: ProgramTrace, ctx, root) -> dict:
 
 
 def excerpt(tr: ProgramTrace, note: str) -> dict:
-    """A cut of ``tr`` around the first boundary between two whole steps
-    of the window: from a little before the first step's last device
-    operation ends to a little after the next step's first one starts (or
-    its seed sync ends).  Operations keep their times; spans are cut at
-    the slice's edges, and a ``bench.window`` marks the slice."""
+    """A cut of the first chip's part of ``tr`` around the first boundary
+    between two whole steps of the window: from a little before the first
+    step's last device operation ends to a little after the next step's
+    first one starts (or its seed sync ends).  Operations keep their
+    times; spans are cut at the slice's edges, and a ``bench.window`` marks
+    the slice."""
     dev = next(iter(tr.ops))
     ops, names = tr.ops[dev], tr.scopes[dev]
     s0, s1 = tr.step_spans()[:2]
@@ -433,7 +458,8 @@ def main(argv=None) -> int:
         rng = np.random.default_rng([args.seed, 0])
         window = driver.run_window(p.svc, p.pool, p.cell.traffic,
                                    args.seconds, rng)
-        tr = traced(p.svc, p.pool, p.cell.traffic, rng)
+        tr = run.traced_stretch(p.svc, p.pool, p.cell.traffic, rng,
+                                len(p.devs))
     finally:
         p.clock.close()
     dev = p.devs[0]

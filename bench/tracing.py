@@ -3,19 +3,24 @@
 The harness wraps each call it makes into the program in a
 ``jax.profiler.TraceAnnotation`` (``bench.submit``, ``bench.step``,
 ``bench.idle_wait``) and the traced stretch in ``bench.window``, so that
-they land in the profiler's trace on the device's clock.  ``load`` reads an
-``.xplane.pb`` into plain tuples; everything after that is arithmetic on
-intervals, checked on a small recorded trace in the tests.
+they land in the profiler's trace on the device's clock.  ``bench.stages``
+reads an ``.xplane.pb`` into plain tuples; everything here is arithmetic on
+intervals, checked on small recorded traces in the tests.
+
+A reduction is defined over the chips the cell used: the device planes that
+ran any operation in the trace (``used_chips``).  A time is each chip's,
+averaged over those chips; an idle gap is a stretch in which none of them
+ran anything.  On one chip each reads what that chip alone reads.
 """
 from __future__ import annotations
 
+import bisect
 import contextlib
+import itertools
 from dataclasses import dataclass, field
-from pathlib import Path
 
 #: the device plane's line that holds one event per executed operation
 OPS_LINE = "XLA Ops"
-SPAN_PREFIX = "bench."
 
 
 @dataclass
@@ -48,32 +53,6 @@ def span(name: str, on: bool, **stats):
     return jax.profiler.TraceAnnotation(name, **stats)
 
 
-def load(trace_dir) -> Trace:
-    """Read the one ``.xplane.pb`` under ``trace_dir``."""
-    from jax.profiler import ProfileData
-
-    files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
-    if len(files) != 1:
-        raise RuntimeError(f"expected one .xplane.pb under {trace_dir}, "
-                           f"found {len(files)}")
-    tr = Trace()
-    for plane in ProfileData.from_file(str(files[0])).planes:
-        is_dev = plane.name.startswith("/device:") and "TPU" in plane.name
-        for line in plane.lines:
-            if is_dev and line.name == OPS_LINE:
-                tr.ops[plane.name] = [
-                    (op_name(e.name), int(e.start_ns),
-                     int(e.start_ns + e.duration_ns)) for e in line.events]
-            elif not is_dev:
-                for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
-                        tr.spans.append(
-                            (e.name, int(e.start_ns),
-                             int(e.start_ns + e.duration_ns), dict(e.stats)))
-    tr.spans.sort(key=lambda s: s[1])
-    return tr
-
-
 def op_name(event_name: str) -> str:
     """``fusion.20`` from the trace's ``%fusion.20 = f32[...] fusion(...)``."""
     return event_name.split(" = ", 1)[0].lstrip("%")
@@ -101,11 +80,33 @@ def busy_ns(intervals, lo=None, hi=None) -> int:
     return sum(b - a for a, b in union(intervals, lo, hi))
 
 
+def per_span(events, spans) -> list:
+    """[the events (label, t0, t1) that overlap each span (name, t0, t1,
+    ...) of ``spans``], each list sorted by start, the longer first at a
+    tie, as ``self_times`` orders them.  Found by bisection, so a reduction
+    per step does not walk the whole trace once a step."""
+    ev = sorted(events, key=lambda e: (e[1], -e[2]))
+    starts = [e[1] for e in ev]
+    reach = list(itertools.accumulate((e[2] for e in ev), max))
+    out = []
+    for s in spans:
+        lo, hi = s[1], s[2]
+        first = bisect.bisect_right(reach, lo)     # all before end by lo
+        stop = bisect.bisect_left(starts, hi)      # all after start at hi
+        out.append([e for e in ev[first:stop] if e[2] > lo])
+    return out
+
+
+def used_chips(tr: Trace) -> dict:
+    """{device: its operations} of each device that ran any operation in
+    the trace: the chips the cell used, in the trace's order."""
+    return {dev: ev for dev, ev in tr.ops.items() if ev}
+
+
 def device_busy_s(tr: Trace) -> float:
-    """Busy seconds in the window, averaged over the devices that ran any
-    operation in the trace (the chips the cell used)."""
+    """Busy seconds in the window, averaged over the chips the cell used."""
     w0, w1 = tr.window()
-    used = [ev for ev in tr.ops.values() if ev]
+    used = used_chips(tr).values()
     if not used:
         raise RuntimeError("trace holds no device operations")
     return sum(busy_ns(ev, w0, w1) for ev in used) / len(used) / 1e9
@@ -118,9 +119,15 @@ def window_s(tr: Trace) -> float:
 
 def step_busy_ms(tr: Trace) -> list:
     """Device busy milliseconds inside each whole ``bench.step`` span of
-    the window (first device)."""
-    ev = next(iter(tr.ops.values()), [])
-    return [busy_ns(ev, s[1], s[2]) / 1e6 for s in tr.step_spans()]
+    the window: each chip's union of its operations, mean over the chips
+    the cell used.  Empty where no chip ran anything."""
+    used = used_chips(tr).values()
+    if not used:
+        return []
+    steps = tr.step_spans()
+    per_chip = [per_span(ev, steps) for ev in used]
+    return [sum(busy_ns(sl[i], s[1], s[2]) for sl in per_chip) / len(used)
+            / 1e6 for i, s in enumerate(steps)]
 
 
 def matching(events, names) -> list:
@@ -153,20 +160,26 @@ def self_times(events, lo, hi) -> dict:
 
 def top_ops(tr: Trace, n: int = 10) -> list:
     """[[name, seconds]] of the device operations that took the most time
-    in the window by their own time, summed by name (first device)."""
+    in the window by their own time: summed by name over the chips the cell
+    used, divided by their count."""
     w0, w1 = tr.window()
-    tot = self_times(next(iter(tr.ops.values()), []), w0, w1)
+    used = used_chips(tr).values()
+    tot: dict = {}
+    for ev in used:
+        for name, ns in self_times(ev, w0, w1).items():
+            tot[name] = tot.get(name, 0) + ns
     best = sorted(((k, v) for k, v in tot.items() if v > 0),
                   key=lambda kv: -kv[1])[:n]
-    return [[name, ns / 1e9] for name, ns in best]
+    return [[name, ns / len(used) / 1e9] for name, ns in best]
 
 
 def idle_gaps(tr: Trace, n: int = 10) -> list:
     """[[span, seconds]] of the longest stretches of the window in which
-    the first device ran nothing, each named by the innermost harness span
-    that held its mid point (``"none"`` where no span did)."""
+    none of the chips the cell used ran anything, each named by the
+    innermost span that held its mid point (``"none"`` where no span did)."""
     w0, w1 = tr.window()
-    busy = union(next(iter(tr.ops.values()), []), w0, w1)
+    busy = union([e for ev in used_chips(tr).values()
+                  for e in ev], w0, w1)
     gaps, t = [], w0
     for a, b in busy + [[w1, w1]]:
         if a > t:

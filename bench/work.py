@@ -27,6 +27,13 @@ def peaks(device_kind: str, path=PEAKS) -> dict:
     return table[device_kind]
 
 
+def shard_rows(n: int, chips: int) -> int:
+    """Corpus rows each chip holds and scans: ``n`` rows divided over the
+    cell's ``chips``, as the program's row-sharded scan divides them
+    (``bench.manifest`` holds ``n`` to a multiple of ``chips``)."""
+    return n // chips
+
+
 def screen_work(rows: int, d1: int, queries: int, query_chunk: int):
     """(flop, bytes) of the stage-1 screen for ``queries`` real queries
     over ``rows`` rows: ``2 * rows * d1`` flop per query, and the lead dims

@@ -89,3 +89,27 @@ def test_screen_work_is_counted_from_real_queries():
     assert work.screen_work(1000, 128, 17, 16)[1] == 4.0 * 1000 * 128 * 2
     t, bound = work.least_time_s(flop, nbytes, work.peaks("TPU v5 lite"))
     assert bound == "bytes" and t == pytest.approx(nbytes / 819e9)
+
+
+@pytest.mark.parametrize("chips, n, what", [
+    (4, 1_000_002, "n 1000002 is not a multiple of its 4 chips"),
+    (4, 1_000_000, None),
+    (1, 1_000_003, None),
+    (2, 1_000_000, "chips 2"),
+    (3, 999_999, "chips 3"),
+], ids=["ragged-rows", "four-chips", "one-chip", "two-chips", "three-chips"])
+def test_shards_are_checked(tmp_path, chips, n, what):
+    """A cell's corpus rows divide evenly over its chips, one shard a
+    chip, and a cell has one chip or four."""
+    root = bench_testutil.tiny_root(tmp_path)
+    path = root / "bench/configs/wiki768-1m.json"
+    cfg = json.loads(path.read_text())
+    cfg["data"]["n"] = n
+    path.write_text(json.dumps(cfg))
+    b = manifest.load(root)
+    b["workloads"][0]["chips"] = chips
+    found = manifest.problems(root, b)
+    if what is None:
+        assert found == []
+    else:
+        assert len(found) == 1 and what in found[0], found
