@@ -247,8 +247,6 @@ def phase_kernels(X, Q, Q_train, ref, method, seed: int,
 
 def phase_mesh(X, Q, ref, seed: int, clock: CompileClock, n_chips: int):
     """The mesh-sharded flat corpus over ``n_chips`` devices."""
-    import jax
-
     mesh = make_host_mesh(n_chips, 1)
     t0 = time.perf_counter()
     sess = open_index(X, index="flat", method="PDScanning+", backend="jax",
@@ -260,18 +258,19 @@ def phase_mesh(X, Q, ref, seed: int, clock: CompileClock, n_chips: int):
     fact("mesh compile s", clock.seconds - c0)
     fact("mesh first batch wall s (includes compile)", res.wall_time_s)
     fact("mesh steady batch wall s", sess.search(Q, K).wall_time_s)
-    # every corpus array must be split over all devices, not parked on one
-    sharded = [a for a in jax.live_arrays() if a.ndim and a.shape[0] == X.shape[0]]
-    require(sharded, "mesh: no corpus-sized device arrays found")
-    for a in sharded:
-        rows = Counter()
+    # every array of the per-shard layout must be split over all devices,
+    # not parked on one: the same number of row blocks on each
+    layout = sess.backend._blocks
+    nb = layout["xl"].shape[0] // n_chips
+    for key, a in layout.items():
+        blocks = Counter()
         for s in a.addressable_shards:
-            rows[s.device.id] += s.data.shape[0]
-        require(len(rows) == n_chips and set(rows.values())
-                == {X.shape[0] // n_chips},
-                f"mesh: corpus array {a.shape} placed as {dict(rows)}")
-    fact("mesh placement", f"{len(sharded)} corpus arrays, each "
-         f"{X.shape[0] // n_chips} rows on each of {n_chips} devices")
+            blocks[s.device.id] += s.data.shape[0]
+        require(len(blocks) == n_chips and set(blocks.values()) == {nb},
+                f"mesh: layout array {key} {a.shape} placed as "
+                f"{dict(blocks)}")
+    fact("mesh placement", f"{len(layout)} layout arrays, each {nb} blocks "
+         f"of {layout['xl'].shape[1]} rows on each of {n_chips} devices")
     require(certified_mask(res).all(), "mesh: uncertified queries")
     check_exact("mesh batch", res.ids, res.dists, X, Q, *ref)
     for d in mesh.devices.flat:

@@ -202,6 +202,11 @@ class JaxBackend:
                 "the adaptive DCO policy is single-device for now — drop "
                 "SchedulePolicy(adaptive=True) on the mesh path "
                 "(DESIGN.md §5)")
+        if mesh is not None and getattr(policy, "engine", "stream") != "stream":
+            raise ValueError(
+                "the mesh path runs the streaming engine over a per-shard "
+                "layout — drop SchedulePolicy(engine=\"two_stage\") on the "
+                "mesh path")
         if mesh is not None and getattr(policy, "guardrails", None) is not None:
             raise ValueError(
                 "guardrails are single-device (the breaker's demotion runs "
@@ -214,12 +219,12 @@ class JaxBackend:
         self.policy = policy
         self.mesh = mesh
         self._dstate = None         # host-side device_state() export
-        self._state = None          # jnp arrays (single-device path)
+        self._state = None          # jnp arrays (single device); on the
+                                    # mesh, each shard's tail_min
         self._blocks = None         # cached stream-engine corpus layout
+                                    # (on the mesh, the per-shard layout)
         self._groups = 1            # resolved PDX dim groups of that layout
-        self._shard_args = None     # device_put shards (mesh path)
         self._mesh_fns: dict = {}   # cfg -> shard_map fn
-        self._mesh_row_block = None  # shard-aligned row_block (mesh path)
         self._list_sizes = None     # IVF partition sizes (probe stats)
         self._cfg_cache: dict = {}  # (k, anytime, demoted) -> DcoEngineConfig
                                     # (same object per call so jit static-arg
@@ -240,7 +245,7 @@ class JaxBackend:
     def invalidate(self):
         """Drop materialized device arrays (full re-materialization on the
         next search; ``notify_append`` is the cheaper delta path for adds)."""
-        self._dstate = self._state = self._blocks = self._shard_args = None
+        self._dstate = self._state = self._blocks = None
         self._groups = 1
         self._list_sizes = None
         self._mesh_fns.clear()
@@ -415,30 +420,18 @@ class JaxBackend:
             self._state = build_device_state(dstate, self._d1)
             self._state.update(extra)
         else:
-            import jax
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            sh = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
-            d1 = self._d1
-            self._shard_args = tuple(
-                jax.device_put(v, sh)
-                for v in (xr[:, :d1], xr[:, d1:],
-                          (xr[:, :d1] ** 2).sum(1), (xr[:, d1:] ** 2).sum(1)))
-            self._mesh_extra_state = rule_scalars(dstate, d1)
-            # certificate sharp edge (make_distributed_topk): a shard whose
-            # row count is not a row_block multiple pads phantom rows inside
-            # the compiled call, weakening the per-shard certificate — so
-            # align row_block to the largest divisor of the shard size
-            # (facade sessions never hit the build-time error)
-            from repro.core.jax_engine import _aligned_row_block
-            n_shards = int(np.prod(tuple(self.mesh.shape.values())))
-            per_shard = max(1, self._n_main // max(n_shards, 1))
-            self._mesh_row_block = _aligned_row_block(
-                per_shard, self.policy.row_block)
+            # the per-shard layout, built once per materialization: the one
+            # resident copy of each shard, padded and masked at the
+            # configured row_block as the one-device layout is
+            from repro.core.jax_engine import shard_stream_blocks
+            self._blocks, self._state = shard_stream_blocks(
+                xr, self._d1, self.policy.row_block, self.mesh,
+                tuple(self.mesh.axis_names))
+            self._mesh_extra_state = rule_scalars(dstate, self._d1)
 
     def engine_config(self, k: int):
         """The resolved ``DcoEngineConfig`` that ``search(Q, k)`` runs
-        (``use_kernel`` decided, PDX groups and mesh row block applied);
+        (``use_kernel`` decided, PDX groups applied);
         materializes the device state on first use."""
         if self._dstate is None:
             self._materialize()
@@ -450,11 +443,9 @@ class JaxBackend:
         if (k, anytime, demoted) in self._cfg_cache:
             return self._cfg_cache[(k, anytime, demoted)]
         ds, p = self._dstate, self.policy
-        row_block = p.row_block if self.mesh is None \
-            else getattr(self, "_mesh_row_block", p.row_block)
         kw = dict(kind=ds["kind"], d1=self._d1, k=k, capacity=p.capacity,
                   query_chunk=p.query_chunk, tau_slack=p.tau_slack,
-                  row_block=row_block, block_capacity=p.block_capacity,
+                  row_block=p.row_block, block_capacity=p.block_capacity,
                   use_kernel=p.use_kernel, dim_groups=self._groups,
                   group_capacity=p.group_capacity)
         if ds["kind"] == "adsampling":
@@ -631,43 +622,41 @@ class JaxBackend:
                     st, ql, qt, cfg, qe, probe, blocks=blocks,
                     deadline_ts=t_end,
                     block_group=self.policy.anytime_block_group)
-            # one batched transfer: the post-jit slices (and the adaptive
-            # report) are tiny lazy dispatches — converting them one
-            # np.asarray at a time serializes a sync per output
-            with span("search.fetch"):
-                out = jax.device_get(out)
-            if engine == "two_stage":
-                d, i, surv = out
-            elif cfg.policy is not None:
-                d, i, surv, passed, dmin, dims_read, shared, report = out
-            elif t_end is not None:
-                d, i, surv, passed, dmin, dims_read, shared, coverage = out
-            else:
-                d, i, surv, passed, dmin, dims_read, shared = out
-            if shared is not None:
-                # blocks each query's chunk completed chunk-shared, over
-                # the blocks it scanned
-                shared = float(shared.mean()) / (
-                    blocks["xl"].shape[0] * (coverage or 1.0))
-            if coverage is not None:
-                # partial scans only touched this fraction of the corpus:
-                # charge candidate work pro rata so pruning stats stay honest
-                cand_per_q = cand_per_q * coverage
         else:
+            # the per-shard layout's scan, gather and global merge; its
+            # counters come back summed over the shards
+            blocks = self._blocks
             if cfg not in self._mesh_fns:
                 self._mesh_fns[cfg] = jax.jit(
                     make_distributed_topk(self.mesh, cfg,
                                           tuple(self.mesh.axis_names),
                                           extra_state=self._mesh_extra_state,
-                                          engine=engine,
-                                          n_rows=self._n_main))
-            d, i, surv, dmin = self._mesh_fns[cfg](*self._shard_args,
-                                                   ql, qt, qe)
-            with span("search.fetch"):
-                surv = np.asarray(surv)     # real completions, psum'd
-                jax.block_until_ready(d)
-            if engine == "two_stage":
-                n_anchor = nq * k * int(np.prod(tuple(self.mesh.shape.values())))
+                                          layout=True))
+            with span("search.dispatch",
+                      chunks=-(-nq // min(cfg.query_chunk, nq))):
+                out = self._mesh_fns[cfg](blocks, self._state, ql, qt, qe)
+        # one batched transfer: the post-jit slices (and the adaptive
+        # report) are tiny lazy dispatches — converting them one
+        # np.asarray at a time serializes a sync per output
+        with span("search.fetch"):
+            out = jax.device_get(out)
+        if engine == "two_stage":
+            d, i, surv = out
+        elif cfg.policy is not None:
+            d, i, surv, passed, dmin, dims_read, shared, report = out
+        elif t_end is not None:
+            d, i, surv, passed, dmin, dims_read, shared, coverage = out
+        else:
+            d, i, surv, passed, dmin, dims_read, shared = out
+        if shared is not None:
+            # blocks each query's chunk completed chunk-shared, over the
+            # blocks it scanned (on the mesh, every shard's blocks)
+            shared = float(shared.mean()) / (
+                blocks["xl"].shape[0] * (coverage or 1.0))
+        if coverage is not None:
+            # partial scans only touched this fraction of the corpus:
+            # charge candidate work pro rata so pruning stats stay honest
+            cand_per_q = cand_per_q * coverage
         with span("search.finish") as sp:
             stats = ScanStats(n_dco=int(cand_per_q.sum()),
                               dims_total=float((cand_per_q * D).sum()))

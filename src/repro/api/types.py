@@ -58,16 +58,17 @@ STAT_EXTRA_KEYS: dict = {
         "reads plus exact-completion tails), the direct evidence that "
         "early exit is firing — compare against D (no pruning) and the "
         "schedule's d1/stage dims.  Measured from the scan itself on the "
-        "stream and host paths (per-group/per-stage alive counts, "
-        "DESIGN.md §8); formula-derived on the legacy two_stage engine "
-        "and the mesh path (screen dims + completed tails).",
+        "stream and host paths, the mesh path included (per-group/"
+        "per-stage alive counts, summed over shards; DESIGN.md §8); "
+        "formula-derived on the legacy two_stage engine (screen dims + "
+        "completed tails).",
     EXTRA_SHARED_BLOCKS:
-        "Streaming engine, single device: mean over the batch's queries "
-        "of the share of scanned row blocks whose screen survivors the "
-        "query's chunk completed through the chunk-shared path (the "
-        "chunk's survivors spanned at most block_capacity distinct rows, "
-        "so none was dropped; DESIGN.md §4).  Absent on the host path, "
-        "the two_stage engine and the mesh path.",
+        "Streaming engine: mean over the batch's queries of the share of "
+        "scanned row blocks whose screen survivors the query's chunk "
+        "completed through the chunk-shared path (the chunk's survivors "
+        "spanned at most block_capacity distinct rows, so none was "
+        "dropped; DESIGN.md §4); on the mesh path, of every shard's "
+        "blocks.  Absent on the host path and the two_stage engine.",
     EXTRA_DRIFT_SCORE:
         "Guardrail sessions only (SchedulePolicy.guardrails armed): the "
         "drift sentinel's EWMA-smoothed query-drift score for this batch, "
@@ -122,8 +123,9 @@ class SchedulePolicy:
     engine — ``"stream"`` (default; block-fused scan with a running top-k,
     core.stream_engine) or ``"two_stage"`` (legacy one-shot engine that
     materializes the (query_chunk, N) estimate matrix; ``capacity`` is its
-    survivor budget).  Streaming knobs: ``row_block`` corpus rows per scan
-    step (bigger = fewer merges, more VMEM/HBM per tile), ``block_capacity``
+    survivor budget; single device only: the mesh path streams).
+    Streaming knobs: ``row_block`` corpus rows per scan step (bigger =
+    fewer merges, more VMEM/HBM per tile), ``block_capacity``
     survivors tail-completed per block per query (must comfortably exceed k;
     the per-block analogue of ``capacity``), ``use_kernel`` routes stage 1
     through the Pallas kernels (None = only on TPU).  See DESIGN.md §4.

@@ -217,41 +217,131 @@ def _aligned_row_block(per_shard: int, row_block: int) -> int:
     return rb
 
 
+def shard_stream_blocks(xr, d1: int, row_block: int, mesh,
+                        shard_axes=("data", "model")):
+    """The streaming layout of a corpus whose rows are divided over
+    ``shard_axes`` of ``mesh``, for :func:`make_distributed_topk` with
+    ``layout=True``.
+
+    ``xr`` is the rotated corpus (N, D) on the host.  Shard ``s`` of ``S``
+    holds rows ``[s*N//S, (s+1)*N//S)`` and lays them out as
+    ``core.stream_engine.build_stream_blocks`` lays out one device's corpus:
+    ``nb`` blocks of ``B = min(row_block, largest shard)`` rows, the last
+    one padded with zero rows whose id is -1, which the scan masks.  Ids are
+    global.  Each shard is built on the host and copied to its devices on
+    its own, so the layout is the one resident copy of the corpus and no
+    device ever holds a second.
+
+    Returns ``(blocks, shard_state)``: ``blocks`` the layout's keys (``xl``,
+    ``xt``, ``lsq``, ``tsq``, ``ids``) as (S * nb, B, ...) arrays sharded on
+    the block axis; ``shard_state`` ``{"tail_min": (S,)}``, each shard's
+    least tail norm over its real rows (the DDCres screen's bound, which a
+    pad row's zero norm would loosen)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    xr = np.asarray(xr, np.float32)
+    n, D = xr.shape
+    d1 = min(int(d1), D)
+    S = int(np.prod([mesh.shape[a] for a in shard_axes]))
+    lo = [s * n // S for s in range(S + 1)]
+    most = max(lo[s + 1] - lo[s] for s in range(S))
+    B = max(1, min(int(row_block), most))
+    nb = max(1, -(-most // B))
+
+    # squared norms of each shard's real lead and tail rows, as
+    # build_device_state computes them
+    norms = [((xr[lo[s]:lo[s + 1], :d1] ** 2).sum(1),
+              (xr[lo[s]:lo[s + 1], d1:] ** 2).sum(1)) for s in range(S)]
+
+    def real(key, s):
+        """The real rows of shard ``s`` of the layout's array ``key``."""
+        a, b = lo[s], lo[s + 1]
+        if key == "xl":
+            return xr[a:b, :d1]
+        if key == "xt":
+            return xr[a:b, d1:]
+        if key == "ids":
+            return np.arange(a, b, dtype=np.int32)
+        return norms[s][key == "tsq"]
+
+    def padded(key, s):
+        """Shard ``s`` of ``key`` as (nb, B, ...) blocks; pad rows are zero
+        with id -1, as build_stream_blocks pads."""
+        a = real(key, s)
+        out = np.full((nb * B,) + a.shape[1:], -1 if key == "ids" else 0,
+                      a.dtype)
+        out[:len(a)] = a
+        return out.reshape(nb, B, *a.shape[1:])
+
+    sharding = NamedSharding(mesh, P(tuple(shard_axes)))
+    width = {"xl": (d1,), "xt": (D - d1,)}
+    blocks = {
+        key: jax.make_array_from_callback(
+            (S * nb, B) + width.get(key, ()), sharding,
+            lambda index, key=key: padded(key, (index[0].start or 0) // nb))
+        for key in ("xl", "xt", "lsq", "tsq", "ids")}
+    tail_min = np.array([t.min() if t.size else 0.0 for _, t in norms],
+                        np.float32)
+    return blocks, {"tail_min": jax.make_array_from_callback(
+        (S,), sharding, lambda index: tail_min[index])}
+
+
 def make_distributed_topk(mesh, cfg: DcoEngineConfig, shard_axes=("data", "model"),
                           extra_state: dict | None = None, engine: str = "stream",
-                          n_rows: int | None = None):
+                          n_rows: int | None = None, layout: bool = False):
     """shard_map engine: dataset rows sharded over ``shard_axes``; queries
     (and per-query ``q_extra`` scalars) replicated; local top-k per shard
     then all-gather + global merge.  The local engine is the streaming
     block-fused scan (core.stream_engine, the default) or the legacy
     ``two_stage`` materializing engine.  ``extra_state`` carries the
     replicated rule scalars from :func:`rule_scalars` (e.g. DADE
-    mass_d1/eps_d1).  Returns (dists (Q, k), ids (Q, k), survivors (Q,),
-    dropped_min_est (Q,)) — survivors is the REAL number of stage-2
-    completions summed over all shards (psum), not a capacity bound;
-    dropped_min_est is the global (pmin) exactness certificate of the
-    streaming engine, +inf for the two-stage engine.
+    mass_d1/eps_d1).
 
-    ``n_rows`` (the total sharded row count) arms build-time validation of
-    the certificate sharp edge: when a shard's row count is not a
-    ``row_block`` multiple, the per-shard streaming layout pads the last
-    block with zero rows *inside* the compiled call, and those phantom
-    rows' estimates can sit under the running tau — weakening each shard's
-    dropped-estimate certificate (and, through the pmin merge, the global
-    one).  Passing ``n_rows`` makes that misalignment a clear build-time
-    error instead of a silently weaker certificate; the jax backend's mesh
-    path auto-aligns ``row_block`` to the shard size before calling, so
-    facade sessions never hit it.  ``None`` preserves the old
-    caller-beware behavior."""
+    With ``layout=True`` the returned function takes ``(blocks,
+    shard_state, q_lead, q_tail, q_extra)``: the per-shard streaming layout
+    of :func:`shard_stream_blocks`, built once and sharded on its block
+    axis, and its ``shard_state``.  Each shard's last block is padded with
+    zero rows of id -1, which the scan masks out of every screen,
+    completion and counter exactly as it masks the padded last block of a
+    one-device layout, and the DDCres screen's ``tail_min`` covers the real
+    rows only, so the padding leaves each shard's certificate as strong as
+    a one-device scan's.  The layout's ids are global.  Without ``layout``
+    it takes the flat row-sharded arrays ``(x_lead, x_tail, lead_sq,
+    tail_sq, q_lead, q_tail, q_extra)``, builds the layout inside every
+    call and offsets each shard's ids by its first row.
+
+    The streaming engine returns ``stream_topk``'s first seven outputs
+    reduced over the shards: (dists (Q, k), ids (Q, k), survivors (Q,),
+    passed (Q,), dropped_min_est (Q,), dims_read (Q,), shared_blocks (Q,)).
+    The counters are summed over shards (psum): real completions, rows that
+    passed the screen, dims read, and blocks completed chunk-shared out of
+    every shard's blocks.  dropped_min_est is the global (pmin) exactness
+    certificate.  The two-stage engine (flat arrays only) returns (dists,
+    ids, survivors, dropped_min_est), the last +inf.  The gather, the
+    global top-k and the reductions run under the ``dco.gather`` scope.
+
+    ``n_rows`` (the total sharded row count, flat arrays only) arms
+    build-time validation of the flat path's certificate sharp edge: there
+    the layout is built inside the call from a shard whose row count is not
+    a ``row_block`` multiple by padding the last block with zero rows, and
+    the id offset turns those rows' -1 ids into real ones, so their
+    estimates can sit under the running tau and weaken each shard's
+    certificate (and, through the pmin merge, the global one).  Passing
+    ``n_rows`` makes that misalignment a build-time error.  ``None``
+    preserves the old caller-beware behavior; ``layout=True`` has no such
+    edge."""
     from jax.sharding import PartitionSpec as P
 
     if engine not in ("stream", "two_stage"):
         raise ValueError(f"engine must be 'stream' or 'two_stage', got {engine!r}")
+    if layout and engine != "stream":
+        raise ValueError("a prebuilt layout is the streaming engine's; the "
+                         "two_stage engine takes the flat arrays")
     if cfg.policy is not None and getattr(cfg.policy, "adaptive", False):
         raise ValueError(
             "the adaptive DCO policy is single-device for now — drop "
             "SchedulePolicy(adaptive=True) on the mesh path (DESIGN.md §5)")
-    if n_rows is not None:
+    if n_rows is not None and not layout:
         n_shards = 1
         for a in shard_axes:
             n_shards *= mesh.shape[a]
@@ -268,40 +358,55 @@ def make_distributed_topk(mesh, cfg: DcoEngineConfig, shard_axes=("data", "model
                 "streaming layout would pad the last block with phantom "
                 "zero rows, weakening every shard's exactness certificate "
                 "(DESIGN.md §4/§10).  Use a row_block that divides the "
-                f"shard size (e.g. {_aligned_row_block(per_shard, cfg.row_block)}) "
-                "or pad the corpus; the facade's mesh path auto-aligns")
+                f"shard size (e.g. {_aligned_row_block(per_shard, cfg.row_block)}), "
+                "pad the corpus, or pass layout=True with shard_stream_blocks")
     extra_state = dict(extra_state or {})
 
-    def local_fn(x_lead, x_tail, lead_sq, tail_sq, q_lead, q_tail, q_extra):
-        state = {"x_lead": x_lead, "x_tail": x_tail,
-                 "lead_sq": lead_sq, "tail_sq": tail_sq, **extra_state}
+    def local_topk(state, blocks, row0, q_lead, q_tail, q_extra):
+        """This shard's top-k, then the global merge and reductions."""
         if engine == "stream":
             from repro.core.stream_engine import stream_topk
-            d, i, surv, _, dmin, _, _ = stream_topk(state, q_lead, q_tail,
-                                                    cfg, q_extra)
+            d, i, surv, passed, dmin, dims, shared = stream_topk(
+                state, q_lead, q_tail, cfg, q_extra, blocks=blocks)
+            summed = (surv, passed, dims, shared)
         else:
             d, i, surv = two_stage_topk(state, q_lead, q_tail, cfg, q_extra)
             dmin = jnp.full(d.shape[0], jnp.inf)
-        # globalize ids with the shard's row offset
-        idx = jax.lax.axis_index(shard_axes[0])
-        if len(shard_axes) > 1:
+            summed = (surv,)
+        if row0 is not None:
+            i = i + row0
+        with jax.named_scope("dco.gather"):
+            dg = jax.lax.all_gather(d, shard_axes, tiled=False)   # (S, Q, k)
+            ig = jax.lax.all_gather(i, shard_axes, tiled=False)
+            dg = jnp.moveaxis(dg, 0, 1).reshape(d.shape[0], -1)   # (Q, S*k)
+            ig = jnp.moveaxis(ig, 0, 1).reshape(d.shape[0], -1)
+            best, pos = jax.lax.top_k(-dg, cfg.k)
+            ids = jnp.take_along_axis(ig, pos, axis=1)
+            summed = [jax.lax.psum(a, shard_axes) for a in summed]
+            dmin = jax.lax.pmin(dmin, shard_axes)   # weakest shard certificate
+        if engine == "stream":
+            surv, passed, dims, shared = summed
+            return -best, ids, surv, passed, dmin, dims, shared
+        return -best, ids, summed[0], dmin
+
+    spec_x = P(shard_axes)      # rows (or row blocks) sharded over the axes
+    if layout:
+        def local_fn(blocks, shard_state, q_lead, q_tail, q_extra):
+            # the scan reads the DDCres bound from tail_min; tail_sq (here
+            # the layout's own, pad rows included) is only looked up
+            state = {**extra_state, **shard_state, "tail_sq": blocks["tsq"]}
+            return local_topk(state, blocks, None, q_lead, q_tail, q_extra)
+        in_specs = (spec_x, spec_x, P(), P(), P())
+    else:
+        def local_fn(x_lead, x_tail, lead_sq, tail_sq, q_lead, q_tail, q_extra):
+            state = {"x_lead": x_lead, "x_tail": x_tail,
+                     "lead_sq": lead_sq, "tail_sq": tail_sq, **extra_state}
+            # globalize ids with the shard's row offset
+            idx = jax.lax.axis_index(shard_axes[0])
             for a in shard_axes[1:]:
                 idx = idx * jax.lax.psum(1, a) + jax.lax.axis_index(a)
-        i = i + idx * x_lead.shape[0]
-        # all-gather per-shard top-k and merge
-        dg = jax.lax.all_gather(d, shard_axes, tiled=False)   # (S, Q, k)
-        ig = jax.lax.all_gather(i, shard_axes, tiled=False)
-        dg = jnp.moveaxis(dg, 0, 1).reshape(d.shape[0], -1)   # (Q, S*k)
-        ig = jnp.moveaxis(ig, 0, 1).reshape(d.shape[0], -1)
-        best, pos = jax.lax.top_k(-dg, cfg.k)
-        surv = jax.lax.psum(surv, shard_axes)   # real completions, all shards
-        dmin = jax.lax.pmin(dmin, shard_axes)   # weakest shard certificate
-        return -best, jnp.take_along_axis(ig, pos, axis=1), surv, dmin
-
-    spec_x = P(shard_axes)      # rows sharded over the product of axes
-    return jax.shard_map(
-        local_fn, mesh=mesh,
-        in_specs=(spec_x, spec_x, spec_x, spec_x, P(), P(), P()),
-        out_specs=(P(), P(), P(), P()),
-        check_vma=False,
-    )
+            return local_topk(state, None, idx * x_lead.shape[0],
+                              q_lead, q_tail, q_extra)
+        in_specs = (spec_x, spec_x, spec_x, spec_x, P(), P(), P())
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)

@@ -22,8 +22,9 @@ SPANS = {
     "search.prep": "the facade's query checks, then the backend's config "
                    "lookup, host rotation and the copy of queries and "
                    "extras to the device",
-    "search.dispatch": "the call into stream_topk until it returns "
-                       "(args: chunks, full_chunks)",
+    "search.dispatch": "the call into stream_topk, or into the mesh "
+                       "program, until it returns (args: chunks, "
+                       "full_chunks)",
     "search.seed_sync": "host read of the adaptive seed's pass fractions",
     "search.fetch": "copy of the engine's outputs to the host, through "
                     "block_until_ready",
@@ -34,7 +35,8 @@ SPANS = {
                          "(args: group)",
 }
 
-#: device scopes, one per stage of ``core.stream_engine``
+#: device scopes, one per stage of ``core.stream_engine`` and one for the
+#: mesh path's merge across shards (``core.jax_engine.make_distributed_topk``)
 SCOPES = {
     "dco.seed": "the adaptive policy's pre-scan seed (_seed_eval)",
     "dco.lead": "stage-1 lead distances and the screen: the dco_scan kernels, "
@@ -46,6 +48,9 @@ SCOPES = {
     "dco.merge": "the running top-k merge and the tau update",
     "dco.scan": "the scan over row blocks itself: its per-block slices of "
                 "the layout and the carry's bookkeeping outside the stages",
+    "dco.gather": "on a mesh, the all_gather of each shard's top-k, the "
+                  "global top_k over them, and the psum/pmin of the "
+                  "shards' counters and certificates",
 }
 
 

@@ -99,7 +99,7 @@ xr = np.asarray(m.state["Xrot"], np.float32)
 sh = NamedSharding(mesh, P(("data","model")))
 a = [jax.device_put(v, sh) for v in (xr[:, :cfg.d1], xr[:, cfg.d1:], (xr[:, :cfg.d1]**2).sum(1), (xr[:, cfg.d1:]**2).sum(1))]
 fn = make_distributed_topk(mesh, cfg)
-dd, ii, ss, dm = fn(*a, Q[:, :cfg.d1], Q[:, cfg.d1:], {})
+dd, ii, ss, _, dm, _, _ = fn(*a, Q[:, :cfg.d1], Q[:, cfg.d1:], {})
 assert float(np.abs(np.sort(np.array(dd),1) - np.sort(np.array(d0),1)).max()) < 1e-3
 ss = np.array(ss)
 assert (ss > 0).all() and (ss <= ds.n).all()      # real completions, all shards

@@ -93,3 +93,64 @@ def test_seed_names_its_ops(tiny):
     hlo = se._seed_eval.lower(st, xs, ql, qt, {}, cfg).compile().as_text()
     ops = _stage_ops(hlo)
     assert ops and all("dco.seed" in o[2].split("/") for o in ops), ops
+
+
+def test_one_chip_program_gains_no_scope(tiny):
+    """The served one-chip program names the same stages as before the
+    mesh's merge had a scope of its own: ``dco.gather`` is the mesh's."""
+    st, xs, ql, qt = tiny
+    hlo = se._stream_topk_padded.lower(st, xs, ql, qt, {}, None,
+                                       _cfg()).compile().as_text()
+    assert "dco.gather" not in hlo
+    named = {p for o in _stage_ops(hlo) for p in o[2].split("/")
+             if p in SCOPES}
+    assert named == {"dco.lead", "dco.compact", "dco.tail", "dco.merge",
+                     "dco.scan"}
+
+
+MESH_CHILD = r"""
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import Mesh
+from repro.core.jax_engine import (DcoEngineConfig, make_distributed_topk,
+                                   shard_stream_blocks)
+mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
+rng = np.random.default_rng(0)
+blocks, shard = shard_stream_blocks(
+    rng.standard_normal((1000, 32)).astype(np.float32), 8, 128, mesh,
+    ("data",))
+cfg = DcoEngineConfig(kind="lb", d1=8, k=10, query_chunk=8, row_block=128,
+                      block_capacity=32, use_kernel=False)
+fn = jax.jit(make_distributed_topk(mesh, cfg, ("data",), layout=True))
+q = jnp.asarray(rng.standard_normal((16, 32)).astype(np.float32))
+print(fn.lower(blocks, shard, q[:, :8], q[:, 8:], {}).compile().as_text())
+"""
+
+
+def test_mesh_gather_and_global_merge_carry_their_scope():
+    """On four devices the mesh program's ``all_gather`` of each shard's
+    top-k, its global ``top_k`` and its ``psum``/``pmin`` all run under
+    ``dco.gather``; every other stage op keeps its engine scope."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="src",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", MESH_CHILD], cwd=root,
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    hlo = proc.stdout
+    _assert_scoped(hlo)
+    collectives = []
+    for line in hlo.splitlines():
+        m = INSTR.match(line)
+        if m and m.group(2) in ("all-gather", "all-reduce"):
+            op = re.search(r'op_name="([^"]*)"', line)
+            collectives.append((m.group(2), op.group(1) if op else ""))
+    assert {c[0] for c in collectives} == {"all-gather", "all-reduce"}
+    assert all(c[1].split("/")[-2] == "dco.gather" for c in collectives), \
+        collectives
+    merges = [o for o in _stage_ops(hlo)
+              if o[2].endswith("top_k") and "_stream_topk_padded" not in o[2]]
+    assert len(merges) == 1 and merges[0][2].split("/")[-2] == "dco.gather"
