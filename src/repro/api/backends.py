@@ -19,8 +19,9 @@ import numpy as np
 
 from repro.core.engine import (EXTRA_COVERAGE, EXTRA_DIMS_READ_MEAN,
                                EXTRA_EST_SAVED_FLOPS, EXTRA_FALLBACK_BLOCKS,
-                               EXTRA_RULE_TIMELINE, EXTRA_SCREEN_PASS_MEAN,
-                               EXTRA_SHARED_BLOCKS, EXTRA_SURVIVORS_MEAN, EXTRA_UNCERTIFIED_MASK,
+                               EXTRA_FULL_SCAN_TILE, EXTRA_RULE_TIMELINE,
+                               EXTRA_SCREEN_PASS_MEAN, EXTRA_SHARED_BLOCKS,
+                               EXTRA_SURVIVORS_MEAN, EXTRA_UNCERTIFIED_MASK,
                                EXTRA_UNCERTIFIED_QUERIES, QueryBatch,
                                ScanStats, scan_topk)
 from repro.core.policy import PolicyConfig, finalize_adaptive_extra
@@ -700,6 +701,9 @@ class JaxBackend:
                     np.asarray(report["est_saved_flops"]).sum())
                 stats.extra[EXTRA_RULE_TIMELINE] = [
                     float(v) for v in np.asarray(report["rule_timeline"])]
+                tile = int(report["full_scan_tile"])
+                stats.extra[EXTRA_FULL_SCAN_TILE] = tile
+                sp.set_metadata(full_scan_tile=tile)
             # anytime coverage (DESIGN.md §7): every query of the batch shares
             # the scanned-block fraction; partial scans are uncertified even if
             # the dropped-estimate certificate held over the scanned prefix

@@ -9,7 +9,8 @@ from repro.core.engine import (EXTRA_AUDIT_RECALL, EXTRA_BREAKER_STATE,
                                EXTRA_COVERAGE, EXTRA_DEGRADED,
                                EXTRA_DIMS_READ_MEAN, EXTRA_DRIFT_SCORE,
                                EXTRA_EST_SAVED_FLOPS, EXTRA_FALLBACK_BLOCKS,
-                               EXTRA_HEDGED, EXTRA_REPLICA,
+                               EXTRA_FULL_SCAN_TILE, EXTRA_HEDGED,
+                               EXTRA_REPLICA,
                                EXTRA_RULE_TIMELINE, EXTRA_SCREEN_PASS_MEAN,
                                EXTRA_SHARED_BLOCKS, EXTRA_SURVIVORS_MEAN, EXTRA_UNCERTIFIED_MASK,
                                EXTRA_UNCERTIFIED_QUERIES, ScanStats,
@@ -69,6 +70,12 @@ STAT_EXTRA_KEYS: dict = {
         "spanned at most block_capacity distinct rows, so none was "
         "dropped; DESIGN.md §4); on the mesh path, of every shard's "
         "blocks.  Absent on the host path and the two_stage engine.",
+    EXTRA_FULL_SCAN_TILE:
+        "Streaming engine, adaptive policy only: query rows of the batch's "
+        "call to the certified full-scan body, which serves every query "
+        "chunk the seed (or the guardrail demotion) sent to fallback in one "
+        "tile padded with zero queries to a power-of-two number of chunks; "
+        "0 when no chunk was forced.  Absent on the host path.",
     EXTRA_DRIFT_SCORE:
         "Guardrail sessions only (SchedulePolicy.guardrails armed): the "
         "drift sentinel's EWMA-smoothed query-drift score for this batch, "

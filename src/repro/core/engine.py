@@ -34,6 +34,8 @@ EXTRA_DIMS_READ_MEAN = "dims_read_mean"          # dims touched per candidate
                                                  # (screen + completed tails)
 EXTRA_SHARED_BLOCKS = "shared_block_share"       # stream: share of scanned
                                                  # blocks completed chunk-shared
+EXTRA_FULL_SCAN_TILE = "full_scan_tile"          # stream, adaptive: query rows
+                                                 # of the full-scan call
 EXTRA_DRIFT_SCORE = "drift_score"                # guardrails: EWMA drift score
 EXTRA_AUDIT_RECALL = "audit_recall"              # guardrails: audited recall EWMA
 EXTRA_BREAKER_STATE = "breaker_state"            # guardrails: breaker state that

@@ -1079,14 +1079,18 @@ def stream_topk(state: dict, q_lead, q_tail, cfg: DcoEngineConfig,
     When ``cfg.policy`` is an adaptive ``core.policy.PolicyConfig`` the
     engine serves blocks adaptively (DESIGN.md §5) and appends an eighth
     return value, a report dict with per-query ``fallback_blocks`` /
-    ``est_saved_flops`` and a per-block ``rule_timeline`` (fraction of query
-    chunks served by fdscan).  Adaptive mode forces ``use_kernel=False`` for
-    the dco_scan stage: the Pallas kernel freezes pruned rows mid-block, so
-    its partials cannot be reused by the fallback branch's full completion
-    (the pq_lookup path is unaffected).  A policy with
-    ``force_fallback=True`` (the guardrail breaker's demotion, DESIGN.md
-    §9) skips the seed entirely and serves EVERY chunk by the dedicated
-    full-scan body — exact and certified by construction.
+    ``est_saved_flops``, a per-block ``rule_timeline`` (fraction of query
+    chunks served by fdscan) and ``full_scan_tile`` (query rows of the
+    batch's full-scan call, 0 when no chunk was forced).  Adaptive mode
+    forces ``use_kernel=False`` for the dco_scan stage: the Pallas kernel
+    freezes pruned rows mid-block, so its partials cannot be reused by the
+    fallback branch's full completion (the pq_lookup path is unaffected).
+    The chunks the seed places in fallback are served together: their
+    queries, padded with zero queries to a power-of-two number of chunks,
+    stream the corpus once in one call of the dedicated full-scan body.  A
+    policy with ``force_fallback=True`` (the guardrail breaker's demotion,
+    DESIGN.md §9) skips the seed entirely and serves EVERY chunk by that
+    body — exact and certified by construction.
 
     ``deadline_ts`` (absolute ``time.monotonic()`` timestamp) arms ANYTIME
     mode (DESIGN.md §7): the corpus is walked in groups of ``block_group``
@@ -1163,14 +1167,19 @@ def _dispatch(sp, state, q_lead, q_tail, cfg, q_extra, probe, blocks,
         return tuple(a[:nq] for a in out)
 
     # ---- adaptive orchestration (DESIGN.md §5) ----------------------------
-    # Per-chunk python dispatch: the seed's pass fraction decides, per query
-    # chunk and BEFORE any block is scanned, whether the chunk enters the
-    # switching scan or the dedicated conditional-free full-scan body.  The
-    # decision is one tiny host sync per batch; keeping it out of the jitted
-    # graph avoids a whole-scan lax.cond, which measurably taxes the
-    # executed branch on CPU.  (IVF probing gets no seed — sampled rows may
-    # not be probe candidates — so probed chunks always run the switching
-    # scan, whose spill gate keeps them certified.)
+    # Python dispatch: the seed's pass fraction decides, per query chunk and
+    # BEFORE any block is scanned, whether the chunk enters the switching
+    # scan or the dedicated conditional-free full-scan body.  The decision
+    # is one tiny host sync per batch; keeping it out of the jitted graph
+    # avoids a whole-scan lax.cond, which measurably taxes the executed
+    # branch on CPU.  Each switching chunk is its own call (its screened
+    # path and chunk-shared completion are per chunk by design); every
+    # forced chunk of the batch is served by ONE call of the full-scan
+    # body, their queries in one tile, so the corpus streams once per batch
+    # for them and the products fill the matrix unit's rows.  (IVF probing
+    # gets no seed — sampled rows may not be probe candidates — so probed
+    # chunks always run the switching scan, whose spill gate keeps them
+    # certified.)
     from repro.core.policy import pass_threshold
     nqp = q_lead.shape[0]
     nchunks = nqp // c
@@ -1198,24 +1207,66 @@ def _dispatch(sp, state, q_lead, q_tail, cfg, q_extra, probe, blocks,
         tau0 = ew0 = None
         chunk_full = np.zeros(nchunks, bool)
     sp.set_metadata(full_chunks=int(chunk_full.sum()))
-    outs = []
-    for ci in range(nchunks):
-        sl = slice(ci * c, (ci + 1) * c)
-        outs.append(_stream_chunk(
-            state, blocks, q_lead[sl], q_tail[sl],
-            {key: v[sl] for key, v in q_extra.items()},
-            None if probe is None else probe[sl], q_valid[sl],
-            None if tau0 is None else tau0[sl],
-            None if ew0 is None else ew0[sl],
-            cfg, bool(chunk_full[ci])))
-    if nchunks == 1:
-        *res, rep = outs[0]
-    else:
-        res = [jnp.concatenate([o[j] for o in outs]) for j in range(7)]
-        rep = {key: jnp.concatenate([o[7][key] for o in outs])
-               for key in ("fb", "saved")}
-        rep["timeline"] = jnp.stack([o[7]["timeline"] for o in outs]).mean(0)
-    report = {"fallback_blocks": rep["fb"][:nq],
-              "est_saved_flops": rep["saved"][:nq],
-              "rule_timeline": jnp.atleast_1d(rep["timeline"])}
-    return tuple(a[:nq] for a in res) + (report,)
+    full = np.flatnonzero(chunk_full)
+    switching = np.flatnonzero(~chunk_full)
+    batch = (q_lead, q_tail, q_extra, probe, q_valid, tau0, ew0)
+
+    def rows_of(sel, n_pad=0):
+        """The batch's arrays at the rows of chunks ``sel`` (ascending),
+        followed by ``n_pad`` zero rows."""
+        if sel.size == nchunks and not n_pad:
+            return batch
+        if sel[-1] - sel[0] + 1 == sel.size:
+            rows = slice(sel[0] * c, (sel[-1] + 1) * c)
+        else:
+            rows = (sel[:, None] * c + np.arange(c)).ravel()
+
+        def take(a):
+            if a is None:
+                return None
+            a = a[rows]
+            if n_pad:
+                a = jnp.pad(a, [(0, n_pad)] + [(0, 0)] * (a.ndim - 1))
+            return a
+        return jax.tree_util.tree_map(take, batch,
+                                      is_leaf=lambda a: a is None)
+
+    # one engine call per switching chunk, and ONE for every forced chunk:
+    # their queries share a tile padded with zero queries to a power-of-two
+    # number of chunks, so the corpus streams once per batch through a
+    # full-height product and a batch of n chunks compiles at most
+    # log2(n) + 1 widths of the body.  ``calls`` is (chunks served, outputs)
+    # in the order their rows are concatenated below.
+    calls = []
+    tile = 0
+    if full.size:
+        g = 1 << (full.size - 1).bit_length()
+        tile = g * c
+        calls.append((full.size, _stream_chunk(
+            state, blocks, *rows_of(full, (g - full.size) * c), cfg, True)))
+    for ci in switching:
+        calls.append((1, _stream_chunk(state, blocks,
+                                       *rows_of(np.array([ci])), cfg, False)))
+    # row of the concatenated outputs that holds each query, in batch order
+    start = np.empty(nchunks, np.int64)
+    start[full] = np.arange(full.size) * c
+    start[switching] = tile + np.arange(switching.size) * c
+    where = (start[:, None] + np.arange(c)).ravel()[:nq]
+    in_order = bool((where == np.arange(nq)).all())
+
+    def gather(parts):
+        a = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        return a[:nq] if in_order else a[where]
+
+    res = [gather([o[j] for _, o in calls]) for j in range(7)]
+    # the per-block fallback share over query chunks: a grouped call
+    # weighs as the number of chunks it served
+    weights = np.array([n for n, _ in calls], np.float32)
+    timeline = (calls[0][1][7]["timeline"] if len(calls) == 1 else
+                jnp.tensordot(weights, jnp.stack(
+                    [o[7]["timeline"] for _, o in calls]), axes=1) / nchunks)
+    report = {"fallback_blocks": gather([o[7]["fb"] for _, o in calls]),
+              "est_saved_flops": gather([o[7]["saved"] for _, o in calls]),
+              "rule_timeline": jnp.atleast_1d(timeline),
+              "full_scan_tile": tile}
+    return tuple(res) + (report,)

@@ -29,7 +29,8 @@ SPANS = {
     "search.fetch": "copy of the engine's outputs to the host, through "
                     "block_until_ready",
     "search.finish": "ScanStats, certificate and stats.extra assembly "
-                     "(args: shared_block_share on the streaming engine)",
+                     "(args: shared_block_share on the streaming engine, "
+                     "full_scan_tile under its adaptive policy)",
     "search.tickets": "the service's fill loop over the batch (args: served)",
     "search.group_sync": "host sync after one anytime block group "
                          "(args: group)",

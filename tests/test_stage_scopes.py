@@ -77,14 +77,20 @@ def test_screened_scan_names_every_stage_op(tiny):
 
 
 def test_forced_full_scan_body_names_every_stage_op(tiny):
+    """At one chunk's rows, and at the multi-chunk tile that serves every
+    forced chunk of a batch in one call."""
     st, xs, ql, qt = tiny
     cfg = _cfg(policy=PolicyConfig(adaptive=True))
-    hlo = se._stream_chunk.lower(
-        st, xs, ql[:8], qt[:8], {}, None, jnp.ones(8, bool),
-        jnp.full(8, 1e9), jnp.zeros(8), cfg, True).compile().as_text()
-    _assert_scoped(hlo)
-    named = {p for o in _stage_ops(hlo) for p in o[2].split("/")}
-    assert named >= {"dco.lead", "dco.tail", "dco.merge"}
+    for rows in (8, 32):
+        qlr = jnp.concatenate([ql, ql])[:rows]
+        qtr = jnp.concatenate([qt, qt])[:rows]
+        hlo = se._stream_chunk.lower(
+            st, xs, qlr, qtr, {}, None, jnp.ones(rows, bool),
+            jnp.full(rows, 1e9), jnp.zeros(rows), cfg,
+            True).compile().as_text()
+        _assert_scoped(hlo)
+        named = {p for o in _stage_ops(hlo) for p in o[2].split("/")}
+        assert named >= {"dco.lead", "dco.tail", "dco.merge"}, rows
 
 
 def test_seed_names_its_ops(tiny):
